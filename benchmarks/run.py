@@ -71,6 +71,9 @@ def main() -> None:
 
     import jax
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     print("name,us_per_call,derived")
 
     # ---- kernel + protocol micro-benchmarks (paper §3.3 hot loop) ----------
@@ -145,7 +148,7 @@ def main() -> None:
         all_checks.extend(rres["checks"])
     section("recsys", sec_recsys)
 
-    # ---- sharded serving: answer-GEMM scaling 1→8 fake devices --------------
+    # ---- sharded serving: answer-GEMM scaling over 1→8 devices --------------
     def sec_sharded():
         from benchmarks import sharded_bench
         sres = sharded_bench.run(fast=args.fast)
@@ -161,7 +164,7 @@ def main() -> None:
         all_checks.extend(sres["checks"])
     section("sharded", sec_sharded)
 
-    # ---- sharded offline build: full build 1→8 fake devices -----------------
+    # ---- sharded offline build: full build over 1→8 devices -----------------
     def sec_build():
         from benchmarks import build_bench
         bld = build_bench.run(fast=args.fast)

@@ -32,7 +32,7 @@ def kb(b):
 def main():
     corp = corpus_lib.make_corpus(0, 600, emb_dim=32, n_topics=12)
     live = LiveIndex.build(corp.texts, corp.embeddings, n_clusters=12,
-                           impl="xla", max_pad_fraction=0.8)
+                           max_pad_fraction=0.8)
     cache = HintCache(live.system.hint, live.system.cfg)
     print(f"built: {live.n_docs} docs, n={live.system.db.n} clusters, "
           f"m={live.system.db.m}; hint download {kb(cache.bytes_downloaded)}")

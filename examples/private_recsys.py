@@ -39,8 +39,7 @@ def main():
     # keyed index is built ONCE offline; per-request cost is independent
     # of how many ids the request touches.
     table = np.asarray(params["emb"]["table"], np.float32)
-    system = pipeline.PirRagSystem.build_keyed(table, kappa=16, impl="xla",
-                                               seed=0)
+    system = pipeline.PirRagSystem.build_keyed(table, kappa=16, seed=0)
 
     # A user's private request: click history + one candidate to score.
     hist = rng.integers(0, cfg.vocab_per_field, (1, cfg.hist_len))

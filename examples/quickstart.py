@@ -21,7 +21,7 @@ def main():
     print("== PIR-RAG quickstart ==")
     corp = corpus_lib.make_corpus(0, n_docs=1200, emb_dim=64, n_topics=16)
     system = pipeline.PirRagSystem.build(
-        corp.texts, corp.embeddings, n_clusters=16, impl="xla",
+        corp.texts, corp.embeddings, n_clusters=16,
         balance_factor=1.3,          # beyond-paper: caps the downlink
     )
     print(f"offline setup: {system.setup_seconds:.2f}s | "

@@ -29,7 +29,7 @@ def main():
     corp = corpus_lib.make_corpus(3, n_docs=args.docs, emb_dim=64,
                                   n_topics=24)
     system = pipeline.PirRagSystem.build(corp.texts, corp.embeddings,
-                                         n_clusters=24, impl="xla")
+                                         n_clusters=24)
     rng = np.random.default_rng(0)
     queries = corp.embeddings[rng.integers(0, args.docs, args.clients)]
 

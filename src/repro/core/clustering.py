@@ -48,10 +48,16 @@ class KMeansResult(NamedTuple):
 
 
 def pairwise_sqdist(x: jax.Array, c: jax.Array) -> jax.Array:
-    """||x_i - c_j||² as a GEMM.  x: (N, d) f32, c: (k, d) f32 → (N, k) f32."""
+    """||x_i - c_j||² as a GEMM.  x: (N, d) f32, c: (k, d) f32 → (N, k) f32.
+
+    Full f32 precision (the TPU's default f32 matmul rounds its inputs to
+    bf16): the client's cluster pick must agree with the build's assignment
+    kernel, or a document near a cluster boundary becomes unreachable.
+    """
     x2 = jnp.sum(x * x, axis=1, keepdims=True)
     c2 = jnp.sum(c * c, axis=1)[None, :]
-    return x2 - 2.0 * (x @ c.T) + c2
+    return x2 - 2.0 * jnp.matmul(x, c.T, precision=jax.lax.Precision.HIGHEST
+                                 ) + c2
 
 
 def resolve_mesh_axes(mesh, mesh_axes=None) -> tuple[tuple[str, ...], int]:
@@ -215,15 +221,15 @@ def _pad_rows(x: np.ndarray | jax.Array, mult: int):
 
 
 def kmeans_fit(key: jax.Array, x: jax.Array, *, k: int, iters: int = 25,
-               n_blocks: int = 1, impl: str = "xla") -> KMeansResult:
+               n_blocks: int = 1, impl: str = "auto") -> KMeansResult:
     """kmeans++ init then `iters` Lloyd steps.  Empty clusters keep centroids.
 
     x: (N, d) f32.  ``n_blocks`` picks the canonical reduction granularity
     (see module docstring); any fixed value gives a deterministic result, and
     matching `kmeans_fit_sharded`'s block count reproduces the sharded fit
     bit-for-bit.  ``impl`` dispatches the assignment kernel
-    (`ops.kmeans_assign`): "xla" everywhere, "pallas"/"auto" for the fused
-    TPU kernel.
+    (`ops.kmeans_assign`): "auto" is the fused Pallas kernel on TPU and the
+    XLA oracle elsewhere.
     """
     xp, valid = _pad_rows(jnp.asarray(x, jnp.float32), n_blocks)
     cents, assign, inertia = _kmeans_fit_host(
@@ -235,7 +241,7 @@ def kmeans_fit(key: jax.Array, x: jax.Array, *, k: int, iters: int = 25,
 def kmeans_fit_sharded(key: jax.Array, x: np.ndarray, *, k: int,
                        iters: int = 25, mesh, mesh_axes=None,
                        n_blocks: int | None = None,
-                       impl: str = "xla") -> KMeansResult:
+                       impl: str = "auto") -> KMeansResult:
     """`kmeans_fit` with the corpus row-sharded over a device mesh.
 
     x: (N, d) f32 (host or device); rows are padded and placed P(axes, None)
@@ -269,11 +275,11 @@ def kmeans_fit_sharded(key: jax.Array, x: np.ndarray, *, k: int,
 # Assignment sweeps
 # ---------------------------------------------------------------------------
 
-def assign_to_centroids(x: jax.Array, cents: jax.Array, *, impl: str = "xla",
+def assign_to_centroids(x: jax.Array, cents: jax.Array, *, impl: str = "auto",
                         mesh=None, mesh_axes=None) -> jax.Array:
     """Nearest-centroid assignment (the client-side cluster pick).  (N,) i32.
 
-    impl="pallas" uses the fused distance+argmin kernel
+    On TPU (or impl="pallas") this is the fused distance+argmin kernel
     (kernels/kmeans_assign.py) — on TPU it avoids materializing the (N, K)
     distance matrix in HBM for corpus-scale assignment sweeps.  ``mesh=``
     row-shards the sweep (x P(axes, None), centroids replicated, zero
@@ -292,9 +298,7 @@ def assign_to_centroids(x: jax.Array, cents: jax.Array, *, impl: str = "xla",
                             NamedSharding(mesh, PartitionSpec()))
         fn = collectives.row_shard_assign(mesh, axes, impl=impl)
         return fn(xs, cr)[0][:n]
-    if impl == "pallas":
-        return ops.kmeans_assign(x, cents, impl="pallas")[0]
-    return jnp.argmin(pairwise_sqdist(x, cents), axis=1).astype(jnp.int32)
+    return ops.kmeans_assign(x, cents, impl=impl)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("blocks",))

@@ -184,11 +184,9 @@ class PirRagSystem:
             cfg, db.row_shards if db.row_shards is not None
             else jnp.asarray(db.matrix), mesh=mesh, mesh_axes=axes)
         t_index = time.perf_counter()
+        # row-sharded like the DB on a mesh: the client's hint strip is
+        # row-local, so its decode splits over the chips with no collective
         hint = jax.block_until_ready(server.setup())
-        if mesh is not None:
-            # the client's one-time hint download: gathered off the mesh so
-            # all client-side decode math stays host-local
-            hint = jnp.asarray(np.asarray(hint))
         t_end = time.perf_counter()
         return cls(centroids=cents, db=db, cfg=cfg, server=server, hint=hint,
                    setup_seconds=t_end - t0, index_seconds=t_index - t0,
@@ -247,8 +245,6 @@ class PirRagSystem:
             else jnp.asarray(db.matrix), mesh=mesh, mesh_axes=axes)
         t_index = time.perf_counter()
         hint = jax.block_until_ready(server.setup())
-        if mesh is not None:
-            hint = jnp.asarray(np.asarray(hint))
         t_hint = time.perf_counter()
         sys = cls(centroids=cents, db=db, cfg=cfg, server=server, hint=hint,
                   setup_seconds=t_hint - t0, index_seconds=t_index - t0,

@@ -80,9 +80,12 @@ class PIRServer:
 
     With ``mesh=`` the DB row-shards over the mesh (the ``chunks`` logical
     axis of `sharding.pir_rules`); rows are zero-padded up to a multiple of
-    the shard count so `shard_map` sees equal slices.  The padding rows are
-    all-zero on both the DB and the hint, so answers/decodes are unaffected
-    — every public method still speaks global (m, ...) shapes.
+    the shard count so `shard_map` sees equal slices (a DB packed in the
+    default 256-byte chunks needs none).  The padding rows are all-zero
+    on both the DB and the hint, so answers/decodes are unaffected — every
+    public method still speaks global (m, ...) shapes.  Each sharded call
+    is one compiled program, the cut back to m rows included, so what it
+    moves between devices shows in that program's HLO.
 
     ``db`` accepts three layouts (all (m, n) uint8 semantics):
 
@@ -186,10 +189,10 @@ class PIRServer:
             return ops.hint_gemm(self.db, self.a_matrix, impl=self.cfg.impl)
         if self._hint_fn is None:
             from repro.distributed import collectives
-            self._hint_fn = collectives.row_shard_gemm(
-                self.mesh, self.mesh_axes, impl=self.cfg.impl)
+            self._hint_fn = self._cut_to_m(collectives.row_shard_gemm(
+                self.mesh, self.mesh_axes, impl=self.cfg.impl))
         a_rep = jax.device_put(self.a_matrix, self._replicated)
-        return self._hint_fn(self.db, a_rep)[:self.cfg.m]
+        return self._hint_fn(self.db, a_rep)
 
     def answer(self, qu: jax.Array) -> jax.Array:
         """Online answer: D·qu mod 2^32.  qu: (n,) or (n, batch) uint32.
@@ -204,15 +207,27 @@ class PIRServer:
             return ans
         if self._answer_fn is None:
             from repro.distributed import collectives
-            self._answer_fn = collectives.row_shard_gemm(
+            self._answer_fn = self._cut_to_m(collectives.row_shard_gemm(
                 self.mesh, self.mesh_axes, impl=self.cfg.impl,
-                q_switch=self.cfg.params.q_switch)
+                q_switch=self.cfg.params.q_switch))
         was_vec = qu.ndim == 1
         q2 = qu[:, None] if was_vec else qu
         ans = self._answer_fn(self.db,
                               jax.device_put(q2, self._replicated))
-        ans = ans[:self.cfg.m]
         return ans[:, 0] if was_vec else ans
+
+    def _cut_to_m(self, fn):
+        """``fn`` with its result cut to the m logical rows, in one program.
+
+        Only an m that the shard count does not divide has pad rows; such a
+        result cannot stay row-sharded, so the cut moves rows between
+        devices.  A DB packed in the default 256-byte chunks has none on
+        up to 256 shards, so its programs move nothing.
+        """
+        if not self._row_pad:
+            return fn
+        m = self.cfg.m
+        return jax.jit(lambda *args: fn(*args)[:m])
 
     def _pad_new_cols(self, cols: jax.Array, new_cols: jax.Array
                       ) -> tuple[jax.Array, jax.Array]:
@@ -254,12 +269,12 @@ class PIRServer:
             return ops.delta_gemm(new_g, old_g, a_j, impl=self.cfg.impl)
         if self._delta_fn is None:
             from repro.distributed import collectives
-            self._delta_fn = collectives.row_shard_delta_gemm(
-                self.mesh, self.mesh_axes, impl=self.cfg.impl)
+            self._delta_fn = self._cut_to_m(collectives.row_shard_delta_gemm(
+                self.mesh, self.mesh_axes, impl=self.cfg.impl))
         return self._delta_fn(
             jax.device_put(new_g, self._db_sharding),
             jax.device_put(old_g, self._db_sharding),
-            jax.device_put(a_j, self._replicated))[:self.cfg.m]
+            jax.device_put(a_j, self._replicated))
 
     def stage_scatter(self, cols: jax.Array, new_cols: jax.Array, *,
                       donate: bool = False) -> jax.Array:

@@ -35,7 +35,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def sharded_embedding_lookup(mesh: Mesh, axis: str):
@@ -54,9 +53,9 @@ def sharded_embedding_lookup(mesh: Mesh, axis: str):
         rows = jnp.where(ok[:, None], rows, 0)
         return jax.lax.psum(rows, axis)
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P(axis, None), P()),
-                     out_specs=P())
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(axis, None), P()),
+                         out_specs=P())
 
 
 def split_s_decode_attention(mesh: Mesh, axis: str, *, scale: float):
@@ -84,9 +83,9 @@ def split_s_decode_attention(mesh: Mesh, axis: str, *, scale: float):
         o_glob = jax.lax.psum(o_loc, axis)
         return o_glob / jnp.maximum(l_glob[..., None], 1e-30)
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P(), P(None, axis), P(None, axis), P()),
-                     out_specs=P())
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(), P(None, axis), P(None, axis), P()),
+                         out_specs=P())
 
 
 @functools.lru_cache(maxsize=None)
@@ -116,9 +115,9 @@ def row_shard_gemm(mesh: Mesh, axes: tuple[str, ...], *, impl: str = "auto",
             ans = lwe.switch_modulus(ans, q_switch)
         return ans
 
-    return jax.jit(shard_map(local, mesh=mesh,
-                             in_specs=(P(axes, None), P()),
-                             out_specs=P(axes, None)))
+    return jax.jit(jax.shard_map(local, mesh=mesh,
+                                 in_specs=(P(axes, None), P()),
+                                 out_specs=P(axes, None)))
 
 
 @functools.lru_cache(maxsize=None)
@@ -136,9 +135,9 @@ def row_shard_delta_gemm(mesh: Mesh, axes: tuple[str, ...], *,
     def local(new_shard, old_shard, a_j):
         return ops.delta_gemm(new_shard, old_shard, a_j, impl=impl)
 
-    return jax.jit(shard_map(local, mesh=mesh,
-                             in_specs=(P(axes, None), P(axes, None), P()),
-                             out_specs=P(axes, None)))
+    return jax.jit(jax.shard_map(local, mesh=mesh,
+                                 in_specs=(P(axes, None), P(axes, None), P()),
+                                 out_specs=P(axes, None)))
 
 
 @functools.lru_cache(maxsize=None)
@@ -161,9 +160,9 @@ def row_shard_scatter(mesh: Mesh, axes: tuple[str, ...], *,
     def local(db_shard, cols, new_shard):
         return db_shard.at[:, cols].set(new_shard)
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(axes, None), P(), P(axes, None)),
-                   out_specs=P(axes, None))
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(axes, None), P(), P(axes, None)),
+                       out_specs=P(axes, None))
     return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
 
@@ -187,8 +186,8 @@ def bucket_shard_gemm(mesh: Mesh, axes: tuple[str, ...]):
                           q_shard.astype(jnp.uint32))
 
     spec = P(axes, None, None)
-    return jax.jit(shard_map(local, mesh=mesh, in_specs=(spec, spec),
-                             out_specs=spec))
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(spec, spec),
+                                 out_specs=spec))
 
 
 def balanced_bucket_order(heights, n_shards: int) -> "np.ndarray":
@@ -260,7 +259,7 @@ def _shard_count(mesh: Mesh, axes: tuple[str, ...]) -> int:
 @functools.lru_cache(maxsize=None)
 def corpus_shard_kmeans(mesh: Mesh, axes: tuple[str, ...], *, k: int,
                         iters: int, n_blocks: int, n: int,
-                        impl: str = "xla"):
+                        impl: str = "auto"):
     """Returns fit(key, x, valid): the corpus-sharded K-means fit.
 
     x: (N_pad, d) f32 sharded P(axes, None) — N_pad a multiple of
@@ -288,15 +287,15 @@ def corpus_shard_kmeans(mesh: Mesh, axes: tuple[str, ...], *, k: int,
             key, x_shard, valid_shard, k=k, iters=iters,
             blocks=n_blocks // shards, n=n, impl=impl, axis=axes)
 
-    return jax.jit(shard_map(local, mesh=mesh,
-                             in_specs=(P(), P(axes, None), P(axes)),
-                             out_specs=(P(), P(axes), P()),
-                             check_rep=False))
+    return jax.jit(jax.shard_map(local, mesh=mesh,
+                                 in_specs=(P(), P(axes, None), P(axes)),
+                                 out_specs=(P(), P(axes), P()),
+                                 check_vma=False))
 
 
 @functools.lru_cache(maxsize=None)
 def row_shard_assign(mesh: Mesh, axes: tuple[str, ...], *,
-                     impl: str = "xla"):
+                     impl: str = "auto"):
     """Returns assign(x, cents): the row-sharded nearest-centroid sweep.
 
     x: (N_pad, d) f32 sharded P(axes, None); cents: (k, d) f32 replicated.
@@ -310,9 +309,9 @@ def row_shard_assign(mesh: Mesh, axes: tuple[str, ...], *,
     def local(x_shard, cents):
         return ops.kmeans_assign(x_shard, cents, impl=impl)
 
-    return jax.jit(shard_map(local, mesh=mesh,
-                             in_specs=(P(axes, None), P()),
-                             out_specs=(P(axes), P(axes))))
+    return jax.jit(jax.shard_map(local, mesh=mesh,
+                                 in_specs=(P(axes, None), P()),
+                                 out_specs=(P(axes), P(axes))))
 
 
 @functools.lru_cache(maxsize=None)
@@ -339,9 +338,9 @@ def row_shard_sqdist(mesh: Mesh, axes: tuple[str, ...], *, n_blocks: int):
             lambda b: clustering.pairwise_sqdist(b, cents), xb
         ).reshape(rows, cents.shape[0])
 
-    return jax.jit(shard_map(local, mesh=mesh,
-                             in_specs=(P(axes, None), P()),
-                             out_specs=P(axes, None)))
+    return jax.jit(jax.shard_map(local, mesh=mesh,
+                                 in_specs=(P(axes, None), P()),
+                                 out_specs=P(axes, None)))
 
 
 def ring_psum(mesh: Mesh, axis: str):
@@ -361,8 +360,8 @@ def ring_psum(mesh: Mesh, axis: str):
         acc, _ = jax.lax.fori_loop(0, n - 1, body, (x, x))
         return acc
 
-    return shard_map(local, mesh=mesh, in_specs=P(axis, None),
-                     out_specs=P(axis, None), check_rep=False)
+    return jax.shard_map(local, mesh=mesh, in_specs=P(axis, None),
+                         out_specs=P(axis, None), check_vma=False)
 
 
 def row_shard_health_check(faults, n_devices: int) -> list[tuple[int, int]]:

@@ -20,10 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, c_ref, best_d_ref, best_i_ref, *, bk: int):
@@ -33,7 +30,7 @@ def _kernel(x_ref, c_ref, best_d_ref, best_i_ref, *, bk: int):
     x2 = jnp.sum(x * x, axis=1, keepdims=True)      # (bn, 1)
     c2 = jnp.sum(c * c, axis=1)[None, :]            # (1, bk)
     scores = x2 - 2.0 * jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())),
+        x, c, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32) + c2    # (bn, bk)
 
     local_i = jnp.argmin(scores, axis=1)            # (bn,)
@@ -65,14 +62,8 @@ def kmeans_assign_pallas(x: jax.Array, c: jax.Array, *, bn: int = 256,
     k_total, d2 = c.shape
     assert d == d2 and n % bn == 0 and k_total % bk == 0
 
-    kwargs = {}
-    if pltpu is not None and not interpret:
-        try:
-            kwargs["compiler_params"] = pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary"))
-        except Exception:  # pragma: no cover
-            pass
-
+    # row-local like its input slice when called inside a shard_map
+    vma = jax.typeof(x).vma | jax.typeof(c).vma
     best_d, best_i = pl.pallas_call(
         functools.partial(_kernel, bk=bk),
         grid=(n // bn, k_total // bk),
@@ -85,10 +76,11 @@ def kmeans_assign_pallas(x: jax.Array, c: jax.Array, *, bn: int = 256,
             pl.BlockSpec((bn, 1), lambda i, k: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
-            jax.ShapeDtypeStruct((n, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n, 1), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((n, 1), jnp.int32, vma=vma),
         ],
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
     )(x, c)
     return best_i[:, 0], best_d[:, 0]

@@ -38,10 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU compiler params are an optional nicety; interpret mode ignores them
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 U32 = jnp.uint32
 I32 = jnp.int32
@@ -97,25 +94,22 @@ def modmatmul_pallas(db: jax.Array, q: jax.Array, *, bm: int = 256,
                      interpret: bool = False) -> jax.Array:
     """(db @ q) mod 2^32 via the limb-decomposed MXU kernel.
 
-    db: (m, n) uint8 — m, n must be multiples of (bm, bn) (ops.py pads).
+    db: (m, n) uint8 — n must be a multiple of bn (ops.py pads); m is
+        free: the last row tile may run past m.  Its out-of-range rows read
+        unspecified values and their output rows are dropped, which is exact
+        because every output row depends on its own DB row only.
     q:  (n, b) uint32 — b must be a multiple of bb.
     returns (m, b) uint32, bitwise equal to ref.modmatmul_ref.
     """
     m, n = db.shape
     n2, b = q.shape
     assert n == n2, (db.shape, q.shape)
-    assert m % bm == 0 and n % bn == 0 and b % bb == 0, (db.shape, q.shape,
-                                                         (bm, bn, bb))
-    grid = (m // bm, b // bb, n // bn)
+    assert n % bn == 0 and b % bb == 0, (db.shape, q.shape, (bm, bn, bb))
+    grid = (pl.cdiv(m, bm), b // bb, n // bn)
 
-    kwargs = {}
-    if pltpu is not None and not interpret:
-        try:
-            kwargs["compiler_params"] = pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"))
-        except Exception:  # pragma: no cover - older API name
-            pass
-
+    # Inside a shard_map that checks varying manual axes the output must say
+    # how it varies: like the DB row slice it is computed from.
+    vma = jax.typeof(db).vma | jax.typeof(q).vma
     return pl.pallas_call(
         functools.partial(_kernel, bn=bn),
         grid=grid,
@@ -124,7 +118,8 @@ def modmatmul_pallas(db: jax.Array, q: jax.Array, *, bm: int = 256,
             pl.BlockSpec((bn, bb), lambda i, j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((bm, bb), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, b), U32),
+        out_shape=jax.ShapeDtypeStruct((m, b), U32, vma=vma),
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(db, q)

@@ -25,6 +25,11 @@ from repro.obs.trace import kernel_annotation
 
 U32 = jnp.uint32
 
+#: Default (bm, bn, bb) tile of the modmatmul kernel.  A DB of any row count
+#: whose column count is a multiple of bn is used in place (the kernel's
+#: last row tile may be partial); other widths cost a padded device copy.
+BLOCK = (256, 512, 128)
+
 
 #: jit'd oracle: fuses the u8→u32 widening into the GEMM instead of
 #: materializing a 4× DB copy per call (measured ~40× on large matvecs).
@@ -44,8 +49,9 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
 class _LayoutCache:
     """Identity-keyed memo for device-resident kernel layouts.
 
-    The Pallas path pads (and thereby re-uploads) its whole database operand
-    on every call; in the serving hot loop the database is the SAME array
+    The Pallas path pads (and thereby copies) a database operand whose width
+    is not whole column tiles on every call; in the serving hot loop the
+    database is the SAME array
     object tick after tick, so the padded layout is cached and reused until a
     commit swaps the array.  Keys carry ``id()`` plus shape/block, and every
     entry pins the source array(s) so an entry can only be returned while its
@@ -134,7 +140,7 @@ def add_delta(hint: jax.Array, delta: jax.Array) -> jax.Array:
 
 
 def modmatmul(db: jax.Array, q: jax.Array, *, impl: str = "auto",
-              block: tuple[int, int, int] = (256, 512, 128)) -> jax.Array:
+              block: tuple[int, int, int] = BLOCK) -> jax.Array:
     """Exact (db @ q) mod 2^32.
 
     db: (m, n) uint8 (entries < plaintext modulus p ≤ 256).
@@ -157,18 +163,20 @@ def modmatmul(db: jax.Array, q: jax.Array, *, impl: str = "auto",
             out = _modmatmul_ref_jit(db, q2)
     elif impl == "pallas":
         bm, bn, bb = block
-        m, n = db.shape
         # Hot-loop reuse: the serving DB is the same array object across
-        # ticks, so its padded device layout is cached instead of re-padded
-        # (and re-uploaded) per call.  Queries change every call — pad inline.
-        dbp = _db_pad_cache.get((id(db), db.shape, bm, bn), (db,),
-                                lambda: _pad_to(_pad_to(db, 0, bm), 1, bn))
+        # ticks, so its column-padded device layout is cached instead of
+        # re-padded per call.  Queries change every call — pad inline.
+        # Under a trace (shard_map, jit) the operand is a tracer: nothing to
+        # reuse, and a tracer must not outlive its trace in the cache.
+        pad = lambda: _pad_to(db, 1, bn)  # noqa: E731
+        dbp = (pad() if isinstance(db, jax.core.Tracer) else
+               _db_pad_cache.get((id(db), db.shape, bn), (db,), pad))
         qp = _pad_to(_pad_to(q2, 0, bn), 1, bb)
         interpret = jax.default_backend() != "tpu"
         with kernel_annotation("pirrag.modmatmul.pallas"):
             out = modmatmul_pallas(dbp, qp, bm=bm, bn=bn, bb=bb,
                                    interpret=interpret)
-        out = out[:m, :q2.shape[1]]
+        out = out[:, :q2.shape[1]]
     else:
         raise ValueError(f"unknown impl {impl!r}")
 
@@ -176,7 +184,7 @@ def modmatmul(db: jax.Array, q: jax.Array, *, impl: str = "auto",
 
 
 def hint_gemm(db: jax.Array, a_mat: jax.Array, *, impl: str = "auto",
-              block: tuple[int, int, int] = (256, 512, 128)) -> jax.Array:
+              block: tuple[int, int, int] = BLOCK) -> jax.Array:
     """Offline hint H = D · A (mod 2^32); same kernel, many query columns."""
     return modmatmul(db, a_mat, impl=impl, block=block)
 
@@ -262,7 +270,7 @@ def bucketed_modmatmul_sharded(stack: jax.Array, qs: jax.Array, mesh,
 
 def bucketed_modmatmul(dbs: Sequence[jax.Array], qs: jax.Array, *,
                        impl: str = "auto",
-                       block: tuple[int, int, int] = (256, 512, 128)
+                       block: tuple[int, int, int] = BLOCK
                        ) -> list[jax.Array]:
     """Per-bucket exact (D_b @ q_b) mod 2^32 — the batch-PIR server op.
 
@@ -318,13 +326,14 @@ def bucketed_modmatmul(dbs: Sequence[jax.Array], qs: jax.Array, *,
         bm, bn, bb = block
         m_pad = max(d.shape[0] for d in dbs)
         m_pad += (-m_pad) % bm
-        stack = _bucket_stack_cache.get(
-            (tuple(id(d) for d in dbs),
-             tuple(d.shape for d in dbs), bm, bn),
-            tuple(dbs),
-            lambda: jnp.stack([_pad_to(jnp.pad(d, ((0, m_pad - d.shape[0]),
-                                                   (0, 0))), 1, bn)
-                               for d in dbs]))
+        restack = lambda: jnp.stack(  # noqa: E731
+            [_pad_to(jnp.pad(d, ((0, m_pad - d.shape[0]), (0, 0))), 1, bn)
+             for d in dbs])
+        stack = (restack() if any(isinstance(d, jax.core.Tracer) for d in dbs)
+                 else _bucket_stack_cache.get(
+                     (tuple(id(d) for d in dbs),
+                      tuple(d.shape for d in dbs), bm, bn),
+                     tuple(dbs), restack))
         qp = _pad_to(_pad_to(q3, 1, bn), 2, bb)
         interpret = jax.default_backend() != "tpu"
         with kernel_annotation("pirrag.bucketed_modmatmul.pallas"):

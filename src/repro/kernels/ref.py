@@ -6,6 +6,7 @@ These oracles are also the production CPU path (`impl="xla"` in ops.py).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 U32 = jnp.uint32
@@ -29,5 +30,6 @@ def kmeans_assign_ref(x: jnp.ndarray, c: jnp.ndarray):
     """Unfused oracle for the k-means assignment kernel."""
     x2 = jnp.sum(x * x, axis=1, keepdims=True)
     c2 = jnp.sum(c * c, axis=1)[None, :]
-    d2 = x2 - 2.0 * (x @ c.T) + c2
+    d2 = x2 - 2.0 * jnp.matmul(x, c.T, precision=jax.lax.Precision.HIGHEST
+                               ) + c2
     return jnp.argmin(d2, axis=1).astype(jnp.int32), jnp.min(d2, axis=1)

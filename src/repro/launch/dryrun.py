@@ -34,7 +34,7 @@ import jax
 from repro.configs import base as cfgbase
 from repro.distributed import sharding as sh
 from repro.launch import hlo_analysis, steps as steps_lib
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "experiments", "dryrun")
@@ -56,8 +56,8 @@ def run_cell(arch_name: str, shape_name: str, multi_pod: bool,
         mesh = make_production_mesh(multi_pod=multi_pod)
         if not multi_pod:
             # single-pod mesh uses 256 of the 512 host devices
-            mesh = jax.make_mesh((16, 16), ("data", "model"),
-                                 devices=jax.devices()[:256])
+            mesh = make_mesh((16, 16), ("data", "model"),
+                             devices=jax.devices()[:256])
         bundle = steps_lib.make_bundle(arch, shape_name, smoke=False)
         rules = dict(bundle.rules_for(multi_pod))
         if rules_override:

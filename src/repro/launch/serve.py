@@ -2,7 +2,10 @@
 
 The engines themselves live in `repro.serve.engine` (synchronous reference
 loop + pipelined plan/dispatch/complete engine with shadow-epoch commits);
-this module keeps the historical import surface alive and parses flags:
+this module keeps the historical import surface alive, parses flags, and
+holds the two steps every caller of the serve path shares — `build_loop`
+(offline build wrapped in an engine) and `run_requests` (submit, tick,
+drain) — which `chip_smoke.py` calls too:
 
     PYTHONPATH=src python -m repro.launch.serve --docs 2000 --requests 64 \
         --engine pipelined --mutate-every 8
@@ -17,16 +20,81 @@ from __future__ import annotations
 import argparse
 import time
 
-import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 # Re-exported for backward compatibility: the serving classes began life in
 # this module and tests/examples import them from here.
 from repro.serve.engine import (DeadlineBatcher, PIRServeLoop,  # noqa: F401
                                 PipelinedServeLoop, Request, Response)
 
 
-def main():  # pragma: no cover - exercised by examples/tests
+def build_loop(texts, embeddings, *, n_clusters: int,
+               engine: str = "pipelined", live: bool = False, mesh=None,
+               replicas: int = 2, faults=None, multi_probe: int = 1,
+               **loop_kw):
+    """Offline build of the index, wrapped in the serving engine.
+
+    Returns ``(loop, live_index, replica_group)``; the last two are None
+    unless ``live`` (mutations) or ``engine="fleet"`` asked for them.
+    Kernels dispatch on the backend (``impl="auto"``): the Pallas MXU
+    kernels on TPU, the exact XLA oracle elsewhere.
+    """
+    from repro.core import pipeline
+    from repro.update import LiveIndex
+
+    loop_cls = (PipelinedServeLoop if engine == "pipelined"
+                else PIRServeLoop)
+    index = group = None
+    if engine == "fleet":
+        from repro.fleet import FleetServeLoop, ReplicaGroup
+        index = LiveIndex.build(texts, embeddings, n_clusters=n_clusters,
+                                mesh=mesh)
+        group = ReplicaGroup.from_live(index, n_replicas=replicas,
+                                       n_shards=4)
+        loop = FleetServeLoop(group, faults=faults, **loop_kw)
+    elif live:
+        index = LiveIndex.build(texts, embeddings, n_clusters=n_clusters,
+                                mesh=mesh)
+        loop = loop_cls(index, **loop_kw)
+    else:
+        system = pipeline.PirRagSystem.build(texts, embeddings,
+                                             n_clusters=n_clusters, mesh=mesh)
+        loop = loop_cls(system, **loop_kw)
+    if multi_probe > 1:
+        loop.system.enable_batch(kappa=max(4, multi_probe))
+    return loop, index, group
+
+
+def run_requests(loop, embeddings, n_requests: int, rng, *, top_k: int = 5,
+                 multi_probe: int = 1, mutate_every: int = 0,
+                 live=None) -> tuple[list[int], float]:
+    """Serve ``n_requests`` queries, one per tick, then drain the loop.
+
+    Request ``rid`` queries with the embedding of a random anchor document;
+    with a live index, every ``mutate_every``-th request also replaces a
+    random document (same embedding, new text), which commits through the
+    delta-hint path.  Returns (anchor doc per rid, wall seconds).
+    """
+    from repro.update import journal as journal_lib
+
+    n_docs = len(embeddings)
+    anchors = []
+    t0 = time.perf_counter()
+    for rid in range(n_requests):
+        anchors.append(int(rng.integers(0, n_docs)))
+        loop.submit(rid, embeddings[anchors[-1]], top_k=top_k,
+                    multi_probe=multi_probe)
+        if live is not None and mutate_every and rid % mutate_every == 0:
+            d = int(rng.integers(0, n_docs))
+            loop.submit_mutation(journal_lib.replace(
+                d, f"refreshed doc {d}".encode(), embeddings[d]))
+        loop.tick()
+    loop.drain()
+    return anchors, time.perf_counter() - t0
+
+
+def main(argv=None):  # pragma: no cover - exercised by examples/tests
     ap = argparse.ArgumentParser()
     ap.add_argument("--docs", type=int, default=2000)
     ap.add_argument("--requests", type=int, default=64)
@@ -83,25 +151,18 @@ def main():  # pragma: no cover - exercised by examples/tests
     ap.add_argument("--metrics", action="store_true",
                     help="print the metrics registry (counters/gauges/"
                          "histograms) as JSON after the run")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
-    from repro.core import pipeline
     from repro.data import corpus as corpus_lib
-    from repro.update import LiveIndex, journal as journal_lib
+    from repro.launch.mesh import make_chunk_mesh
 
-    mesh = None
-    if args.shard > 1:
-        n_dev = len(jax.devices())
-        assert args.shard <= n_dev, (args.shard, n_dev)
-        mesh = jax.make_mesh((args.shard,), ("chunks",),
-                             devices=jax.devices()[:args.shard])
+    mesh = make_chunk_mesh(args.shard) if args.shard > 1 else None
 
     from repro.obs import Obs
 
     corp = corpus_lib.make_corpus(0, args.docs, emb_dim=64, n_topics=24)
     rng = np.random.default_rng(0)
-    loop_cls = (PipelinedServeLoop if args.engine == "pipelined"
-                else PIRServeLoop)
     obs = Obs(trace=args.trace is not None)
     loop_kw = dict(max_batch=args.max_batch, deadline_ms=args.deadline_ms,
                    obs=obs)
@@ -114,10 +175,9 @@ def main():  # pragma: no cover - exercised by examples/tests
         loop_kw["depth"] = args.depth
         if gen is not None:
             loop_kw["gen_coalesce"] = args.gen_coalesce
-    group = None
+    faults = None
     if args.engine == "fleet":
-        from repro.fleet import FaultPlan, FleetServeLoop, ReplicaGroup
-        faults = None
+        from repro.fleet import FaultPlan
         if args.shard_loss is not None:
             at, dev, down = (int(x) for x in args.shard_loss.split(":"))
             faults = FaultPlan.single_shard_loss(
@@ -126,36 +186,15 @@ def main():  # pragma: no cover - exercised by examples/tests
             faults = FaultPlan.random(
                 args.chaos, n_events=6, horizon=max(args.requests // 2, 8),
                 n_devices=args.replicas * 4).compile()
-        live = LiveIndex.build(corp.texts, corp.embeddings,
-                               n_clusters=24, impl="xla", mesh=mesh)
-        group = ReplicaGroup.from_live(live, n_replicas=args.replicas,
-                                       n_shards=4)
-        loop = FleetServeLoop(group, faults=faults, **loop_kw)
-    elif args.mutate_every > 0:
-        live = LiveIndex.build(corp.texts, corp.embeddings,
-                               n_clusters=24, impl="xla", mesh=mesh)
-        loop = loop_cls(live, **loop_kw)
-    else:
-        live = None
-        system = pipeline.PirRagSystem.build(corp.texts, corp.embeddings,
-                                             n_clusters=24, impl="xla",
-                                             mesh=mesh)
-        loop = loop_cls(system, **loop_kw)
-
-    if args.multi_probe > 1:
-        loop.system.enable_batch(kappa=max(4, args.multi_probe))
+    loop, live, group = build_loop(
+        corp.texts, corp.embeddings, n_clusters=24, engine=args.engine,
+        live=args.mutate_every > 0, mesh=mesh, replicas=args.replicas,
+        faults=faults, multi_probe=args.multi_probe, **loop_kw)
 
     t0 = time.perf_counter()
-    for rid in range(args.requests):
-        q = corp.embeddings[rng.integers(0, args.docs)]
-        loop.submit(rid, q, top_k=args.top_k, multi_probe=args.multi_probe)
-        if live is not None and args.mutate_every and rid % args.mutate_every == 0:
-            d = int(rng.integers(0, args.docs))
-            loop.submit_mutation(journal_lib.replace(
-                d, f"refreshed doc {d}".encode(), corp.embeddings[d]))
-        loop.tick()
-    loop.drain()
-    dt = time.perf_counter() - t0
+    _, dt = run_requests(loop, corp.embeddings, args.requests, rng,
+                         top_k=args.top_k, multi_probe=args.multi_probe,
+                         mutate_every=args.mutate_every, live=live)
     if not loop.responses:
         print(f"served 0 requests in {dt:.2f}s")
         return

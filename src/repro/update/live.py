@@ -343,8 +343,7 @@ class LiveIndex:
                       else system.server.stage_scatter(cols_j, new_cols_j))
         # u32 wraparound: exact.  ΔH is transient, so the add donates ITS
         # buffer; the old hint array survives for in-flight decode snapshots.
-        new_hint = (ops.add_delta(system.hint, delta_h)
-                    if system.mesh is None else system.hint + delta_h)
+        new_hint = ops.add_delta(system.hint, delta_h)
         # Batch-PIR replicas (if enabled) take the same exact delta, routed
         # to each touched cluster's owning buckets.
         staged_batch = routing.stage_batch_hints(system, cols, new_cols,

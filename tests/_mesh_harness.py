@@ -12,15 +12,16 @@ Usage (from any test module):
 
     def test_something_on_8_devices():
         run_sub('''
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     ...
     print("OK")
     ''')
 
-The prelude the body runs under imports jax/jnp/np and the sharding names
-(`Mesh`, `NamedSharding`, `P`) and asserts the device count, so bodies can
-use them directly.  `run_sub` asserts a zero exit status and returns the
-child's stdout for content assertions.
+The prelude the body runs under imports jax/jnp/np, the sharding names
+(`Mesh`, `NamedSharding`, `P`) and `make_mesh` (`repro.launch.mesh`: every
+axis ``Auto``, as the code under test expects) and asserts the device
+count, so bodies can use them directly.  `run_sub` asserts a zero exit
+status and returns the child's stdout for content assertions.
 """
 from __future__ import annotations
 
@@ -32,6 +33,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n}"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_mesh
 assert jax.device_count() == {n}, jax.device_count()
 """
 

@@ -80,7 +80,7 @@ from repro.update import LiveIndex
 
 rng = np.random.default_rng(4)
 table = rng.standard_normal((600, 8)).astype(np.float32)
-mesh = jax.make_mesh((8,), ("chunks",))
+mesh = make_mesh((8,), ("chunks",))
 live1 = LiveIndex.build_keyed(table, kappa=8, impl="xla", seed=0)
 live8 = LiveIndex.build_keyed(table, kappa=8, impl="xla", seed=0, mesh=mesh)
 sys1, sys8 = live1.system, live8.system

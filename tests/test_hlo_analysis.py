@@ -59,7 +59,8 @@ def test_matmul_tp_collectives_and_flops():
     """Megatron-style 2-way TP matmul: per-device flops = half; all-reduce
     wire bytes match 2·S·(g-1)/g."""
     run_sub("""
-mesh = jax.make_mesh((1, 2), ("data", "model"))
+mesh = jax.make_mesh((1, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 B, D, F = 32, 128, 256
 x = jax.ShapeDtypeStruct((B, D), jnp.float32, sharding=NamedSharding(mesh, P(None, None)))
 w1 = jax.ShapeDtypeStruct((D, F), jnp.float32, sharding=NamedSharding(mesh, P(None, "model")))

@@ -261,7 +261,7 @@ class FakeClock:
     def __init__(self): self.t = 0.0
     def __call__(self): self.t += 1e-4; return self.t
 
-mesh = jax.make_mesh((8,), ("chunks",))
+mesh = make_mesh((8,), ("chunks",))
 corp = corpus_lib.make_corpus(1, 160, emb_dim=16, n_topics=6)
 
 def build(m):

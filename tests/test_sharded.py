@@ -56,8 +56,7 @@ mesh = jax.make_mesh((8,), ("data",))
 x = jnp.arange(8 * 4, dtype=jnp.float32).reshape(8, 4)
 x_sh = jax.device_put(x, NamedSharding(mesh, P("data", None)))
 # each device contributes its (1,4) row; ring sum = column-sum broadcast
-from jax.experimental.shard_map import shard_map
-fn = jax.jit(shard_map(lambda b: b, mesh=mesh, in_specs=P("data", None), out_specs=P("data", None)))
+fn = jax.jit(jax.shard_map(lambda b: b, mesh=mesh, in_specs=P("data", None), out_specs=P("data", None)))
 got = ring_psum(mesh, "data")(x_sh)
 np.testing.assert_allclose(np.asarray(got)[0], np.asarray(x).sum(0) / 1.0, rtol=1e-6)
 print("OK")
@@ -80,7 +79,8 @@ state = bundle.init_state(jax.random.PRNGKey(1))
 ref_state, ref_out = jax.jit(bundle.fn)(
     jax.tree.map(lambda x: x, state), jax.tree.map(lambda x: x, batch))
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rules = bundle.rules_for(False)
 state_sh = sh.shardings_from_axes(mesh, bundle.state_axes, rules)
 batch_sh = {k: NamedSharding(mesh, P(*[rules.get(a) for a in ax]))

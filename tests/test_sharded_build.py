@@ -38,7 +38,7 @@ for seed, n_docs, k, d, bf in CASES:
     probe = corp.embeddings[seed + 5]
     top_ref, _ = ref.query(probe, top_k=4, key=jax.random.PRNGKey(seed))
     for n_dev in (2, 8):
-        mesh = jax.make_mesh((n_dev,), ("chunks",),
+        mesh = make_mesh((n_dev,), ("chunks",),
                              devices=jax.devices()[:n_dev])
         got = pipeline.PirRagSystem.build(corp.texts, corp.embeddings,
                                           mesh=mesh, **kw)
@@ -70,7 +70,7 @@ for seed, n, d, k in [(0, 1203, 32, 13), (1, 777, 16, 9)]:
                                 n_blocks=8)
     cents = np.asarray(ref.centroids)
     for n_dev in (2, 4, 8):
-        mesh = jax.make_mesh((n_dev,), ("chunks",),
+        mesh = make_mesh((n_dev,), ("chunks",),
                              devices=jax.devices()[:n_dev])
         got = clustering.kmeans_fit_sharded(key, x, k=k, iters=7,
                                             mesh=mesh, n_blocks=8)
@@ -111,7 +111,7 @@ def mutate_and_rebuild(mesh):
     assert li.commits[-1].full_rebuild
     return li
 
-mesh = jax.make_mesh((8,), ("chunks",))
+mesh = make_mesh((8,), ("chunks",))
 ref = mutate_and_rebuild(None)
 got = mutate_and_rebuild(mesh)
 # the rebuilt epoch went through the SAME sharded build, not a host-side

@@ -28,7 +28,7 @@ from repro.data import corpus as corpus_lib
 from repro.update import LiveIndex
 
 corp = corpus_lib.make_corpus(0, 300, emb_dim=24, n_topics=8)
-mesh = jax.make_mesh((8,), ("chunks",))
+mesh = make_mesh((8,), ("chunks",))
 live1 = LiveIndex.build(corp.texts, corp.embeddings, n_clusters=8,
                         impl="xla", kmeans_iters=8)
 live8 = LiveIndex.build(corp.texts, corp.embeddings, n_clusters=8,
@@ -120,7 +120,7 @@ def test_sharded_answer_and_bucket_paths_have_no_collectives():
     collective ops — the `pir_rules` zero-collective claim, executed."""
     run_sub("""
 from repro.distributed import collectives
-mesh = jax.make_mesh((8,), ("chunks",))
+mesh = make_mesh((8,), ("chunks",))
 fn = collectives.row_shard_gemm(mesh, ("chunks",), impl="xla",
                                 q_switch=1 << 16)
 db = jax.device_put(jnp.zeros((512, 128), jnp.uint8),
@@ -156,7 +156,7 @@ from repro.launch.serve import PIRServeLoop
 from repro.update import LiveIndex, journal as journal_lib
 
 corp = corpus_lib.make_corpus(1, 200, emb_dim=16, n_topics=6)
-mesh = jax.make_mesh((8,), ("chunks",))
+mesh = make_mesh((8,), ("chunks",))
 live = LiveIndex.build(corp.texts, corp.embeddings, n_clusters=6,
                        impl="xla", kmeans_iters=6, mesh=mesh)
 assert live.system.server.mesh is not None
@@ -206,7 +206,7 @@ from repro.update import LiveIndex
 
 SEED, N_MUTS = {seed}, {n_muts}
 corp = corpus_lib.make_corpus(SEED, 160, emb_dim=16, n_topics=5)
-mesh = jax.make_mesh((8,), ("chunks",))
+mesh = make_mesh((8,), ("chunks",))
 live = LiveIndex.build(corp.texts, corp.embeddings, n_clusters=5,
                        impl="xla", kmeans_iters=5, mesh=mesh)
 live.system.enable_batch(kappa=4, seed=11)
@@ -282,7 +282,7 @@ rng = np.random.default_rng(0)
 m, n = 516, 96            # m % 8 != 0 -> exercises row padding
 db = rng.integers(0, 256, (m, n), dtype=np.uint8)
 cfg = pir.make_config(m, n, impl="xla")
-mesh = jax.make_mesh((8,), ("chunks",))
+mesh = make_mesh((8,), ("chunks",))
 s1 = pir.PIRServer(cfg, jnp.asarray(db))
 s8 = pir.PIRServer(cfg, jnp.asarray(db), mesh=mesh)
 np.testing.assert_array_equal(np.asarray(s1.setup()),
