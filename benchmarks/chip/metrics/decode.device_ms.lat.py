@@ -1,0 +1,24 @@
+"""Device time per batch of the programs after the answer kernel.
+
+From the end of each ``jit_modmatmul_pallas`` program to the next tick's
+first program (its key split, ``jit__threefry_split``): the cut of the
+answer to the batch's columns, the modulus switch, and the client's hint
+strip and decode that produce the cluster bytes.
+"""
+KERNEL = "jit_modmatmul_pallas"
+NEXT = "jit__threefry_split"
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    per_batch, cur = [], None
+    for name, _, dur in run.trace.modules:
+        if name == KERNEL:
+            cur = 0.0
+        elif cur is not None and name == NEXT:
+            per_batch.append(cur)
+            cur = None
+        elif cur is not None:
+            cur += dur
+    return 1e3 * sum(per_batch) / len(per_batch) if per_batch else None

@@ -1,0 +1,145 @@
+"""What the benchmark observes of the timed path, and what it may plant in it.
+
+`Tap` wraps the serving system's plan entry (``query_batch_async``) and the
+server's ``answer``: it records every dispatched batch, keeps the inputs
+and outputs of a sample of batches for the comparison, and names the plan
+and complete stages for the profiler.  It wraps instance attributes of the
+objects the benchmark built, in its own process; the program's code is not
+touched.
+
+`lowlimb_answer` is the control and `fault` the planted faults: answers put
+in the program's place to show that the comparison fails them.
+"""
+from __future__ import annotations
+
+import dataclasses
+import math
+
+import numpy as np
+from jax.profiler import TraceAnnotation
+
+
+@dataclasses.dataclass
+class Batch:
+    """One answer dispatched by the engine."""
+    b: int                  # real query columns
+
+
+class Tap:
+    """Records batches and keeps a sample of them (see the module doc).
+
+    ``replace(orig_answer, qu)``, when given, computes the answer instead
+    of the program.
+    """
+
+    def __init__(self, system, rng: np.random.Generator, p_capture: float,
+                 replace=None):
+        self.rng, self.p = rng, p_capture
+        self.batches: list[Batch] = []
+        self.captured: list[dict] = []
+        self._cur = None
+        self._qba = system.query_batch_async
+        self._answer = system.server.answer
+        self._replace = replace
+        system.query_batch_async = self.query_batch_async
+        system.server.answer = self.answer
+
+    def answer(self, qu):
+        ans = (self._answer(qu) if self._replace is None
+               else self._replace(self._answer, qu))
+        if self._cur is not None:
+            self._cur["qu"], self._cur["ans"] = qu, ans
+        return ans
+
+    def query_batch_async(self, embs, **kw):
+        cap = ({"embs": np.array(embs), "top_k": list(kw["top_k"])}
+               if self.rng.random() < self.p else None)
+        self._cur = cap
+        with TraceAnnotation("bench.plan"):
+            infl = self._qba(embs, **kw)
+        self._cur = None
+        self.batches.append(Batch(len(embs)))
+        inner = infl._complete
+        if cap is not None:
+            cap["cols"] = infl.pending[0]
+            self.captured.append(cap)
+
+        def complete():
+            with TraceAnnotation("bench.complete"):
+                out = inner()
+            if cap is not None:
+                cap["results"] = out
+            return out
+
+        infl._complete = complete
+        return infl
+
+
+class CompileCounter:
+    """Counts programs lowered (compiled or loaded from cache) while on."""
+
+    def __init__(self):
+        import jax
+        self.on, self.n = False, 0
+        jax.monitoring.register_event_duration_secs_listener(self._event)
+
+    def _event(self, event: str, duration: float, **_):
+        if self.on and event.endswith("jaxpr_to_mlir_module_duration"):
+            self.n += 1
+
+
+def lowlimb_answer(shape: tuple[int, int], q_switch: int):
+    """The control: ``f(db, qu)``, the answer computed plainly with each
+    query word's lowest 8-bit limb dropped (three limbs of four),
+    modulus-switched as the server's answer is."""
+    import jax
+    import jax.numpy as jnp
+
+    m, n = shape
+    rows = 256
+    shift = 32 - int(math.log2(q_switch))
+
+    @jax.jit
+    def f(d, qu):
+        qm = qu & jnp.uint32(0xFFFFFF00)
+        blocks = d[:m - m % rows].reshape(-1, rows, n)
+        out = jax.lax.map(lambda blk: jnp.matmul(blk.astype(jnp.uint32), qm),
+                          blocks).reshape(-1, qu.shape[1])
+        if m % rows:
+            out = jnp.concatenate([out, jnp.matmul(
+                d[m - m % rows:].astype(jnp.uint32), qm)])
+        half = jnp.uint32(1 << (shift - 1))
+        return ((out + half) >> jnp.uint32(shift)).astype(jnp.uint16)
+
+    return f
+
+
+def control():
+    """``replace`` that puts the control in the answer's place."""
+    fns: dict = {}
+
+    def replace(orig, qu):
+        srv = orig.__self__
+        if srv.db.shape not in fns:
+            fns[srv.db.shape] = lowlimb_answer(srv.db.shape,
+                                               srv.cfg.params.q_switch)
+        return fns[srv.db.shape](srv.db, qu)
+
+    return replace
+
+
+def fault(kind: str):
+    """A planted fault in the answer, for the harness's own tests."""
+    import jax.numpy as jnp
+
+    def altered(orig, qu):          # one word of one answer changed
+        ans = orig(qu)
+        return ans.at[-1, 0].add(jnp.asarray(1 << 14, ans.dtype))
+
+    def half(orig, qu):             # half of the batch left out
+        h = (qu.shape[1] + 1) // 2
+        ans = orig(qu[:, :h])
+        return jnp.concatenate(
+            [ans, jnp.repeat(ans[:, :1], qu.shape[1] - h, axis=1)], axis=1)
+
+    return {"altered": altered, "half": half}[kind]
