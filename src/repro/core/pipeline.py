@@ -10,6 +10,7 @@ results; its entire view is one pseudorandom uint32 vector per query.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 import os
@@ -49,6 +50,11 @@ class InflightBatch:
         out = self._complete()
         self.done = True
         return out
+
+
+def _no_span(name: str, **attrs):
+    """Stand-in for `Obs.span` where the caller passed no `Obs`."""
+    return contextlib.nullcontext()
 
 
 def _fresh_client_key() -> jax.Array:
@@ -553,7 +559,8 @@ class PirRagSystem:
                           top_k: int | Sequence[int] = 10,
                           multi_probe: int = 1,
                           seed: int | None = None,
-                          key: jax.Array | None = None) -> InflightBatch:
+                          key: jax.Array | None = None,
+                          obs=None) -> InflightBatch:
         """Plan + dispatch a serving batch; decode deferred to `complete()`.
 
         The pipelined serving engine's staged entry point: the returned
@@ -563,7 +570,16 @@ class PirRagSystem:
         while this one computes.  `query_batch` is literally
         ``query_batch_async(...).complete()`` — the two paths cannot
         diverge.
+
+        With an `Obs` (``repro.obs``), the plan's stages open spans on it:
+        ``serve.plan.pick`` (the cluster pick, whose distances come back
+        to the host — it waits for the device work queued ahead of it),
+        ``serve.plan.encrypt`` (the client's A and every LWE encrypt) and
+        ``serve.plan.dispatch`` (answer and decode enqueued); the legacy
+        path's `complete()` opens ``serve.complete.fetch`` around its one
+        device-to-host copy.
         """
+        span = obs.span if obs is not None else _no_span
         if key is None:
             key = (jax.random.PRNGKey(seed) if seed is not None
                    else self.next_query_key())
@@ -574,36 +590,40 @@ class PirRagSystem:
 
         if multi_probe > 1 and self.batch is not None:
             return self._query_batch_via_batchpir_async(query_embs, top_ks,
-                                                        multi_probe, key)
+                                                        multi_probe, key, span)
 
         # Legacy path: P one-hot columns per request (P=1 is the classic
         # one-column-per-client GEMM) — never silently fewer probes than
         # asked for just because batch-PIR isn't enabled.
         p = max(1, multi_probe)
-        # plan: the client object snapshots cfg + hint at THIS epoch
-        client = pir.PIRClient(self.cfg, self.hint)
         emb_dim = self.db.emb_dim
-        d2 = np.asarray(clustering.pairwise_sqdist(
-            jnp.asarray(query_embs, jnp.float32),
-            jnp.asarray(self.centroids)))
-        orders = np.argsort(d2, axis=1)[:, :p]               # (B, P)
-        qs, states = [], []
-        for b in range(len(query_embs)):
-            for j, c in enumerate(orders[b]):
-                qu, st = client.query(jax.random.fold_in(key, b * p + j),
-                                      int(c))
-                qs.append(qu)
-                states.append(st)
+        with span("serve.plan.pick"):
+            d2 = np.asarray(clustering.pairwise_sqdist(
+                jnp.asarray(query_embs, jnp.float32),
+                jnp.asarray(self.centroids)))
+            orders = np.argsort(d2, axis=1)[:, :p]           # (B, P)
+        with span("serve.plan.encrypt"):
+            # the client object snapshots cfg + hint at THIS epoch
+            client = pir.PIRClient(self.cfg, self.hint)
+            qs, states = [], []
+            for b in range(len(query_embs)):
+                for j, c in enumerate(orders[b]):
+                    qu, st = client.query(
+                        jax.random.fold_in(key, b * p + j), int(c))
+                    qs.append(qu)
+                    states.append(st)
         # dispatch: enqueue the GEMM AND the batched recover — the whole
         # answer→plaintext chain rides the device stream, so `complete`
         # is pure host work (one ready-array fetch + parse + rerank) and
         # never queues behind other in-flight device chains
-        ans = self.server.answer(jnp.stack(qs, axis=1))      # (m, B·P)
-        cols = client.recover_batch(
-            ans, jnp.stack([st.secret for st in states], axis=1))
+        with span("serve.plan.dispatch"):
+            ans = self.server.answer(jnp.stack(qs, axis=1))  # (m, B·P)
+            cols = client.recover_batch(
+                ans, jnp.stack([st.secret for st in states], axis=1))
 
         def complete():
-            cols_np = np.asarray(cols)
+            with span("serve.complete.fetch"):
+                cols_np = np.asarray(cols)
             out = []
             for b in range(len(query_embs)):
                 docs = []
@@ -618,7 +638,8 @@ class PirRagSystem:
 
     def _query_batch_via_batchpir_async(self, query_embs: np.ndarray,
                                         top_ks: list[int], multi_probe: int,
-                                        key: jax.Array) -> InflightBatch:
+                                        key: jax.Array, span
+                                        ) -> InflightBatch:
         """Multi-probe serving batch: C clients × B buckets, one GEMM call.
 
         Per-client placement failures (negligible probability) fall back to
@@ -626,32 +647,39 @@ class PirRagSystem:
         the bucketed pass.  Decode state — the per-bucket hints and configs,
         which a later commit patches IN the shared lists — is snapshotted at
         plan time so `complete()` decodes against this batch's epoch.
+        ``span`` opens the plan's pick/encrypt/dispatch spans (see
+        `query_batch_async`); `complete()` decodes per client and has no
+        single fetch to time.
         """
         from repro.batchpir import PlacementError
         bp = self.batch
         emb_dim = self.db.emb_dim
-        d2 = np.asarray(clustering.pairwise_sqdist(
-            jnp.asarray(query_embs, jnp.float32),
-            jnp.asarray(self.centroids)))
-        orders = np.argsort(d2, axis=1)[:, :multi_probe]
+        with span("serve.plan.pick"):
+            d2 = np.asarray(clustering.pairwise_sqdist(
+                jnp.asarray(query_embs, jnp.float32),
+                jnp.asarray(self.centroids)))
+            orders = np.argsort(d2, axis=1)[:, :multi_probe]
 
         per_client, fallback = [], {}
-        for i in range(len(query_embs)):
-            k_i = jax.random.fold_in(key, i)
-            try:
-                qs, st = bp.client.query(k_i, [int(c) for c in orders[i]])
-                per_client.append((qs, st))
-            except PlacementError:
-                fallback[i] = self.query(query_embs[i], top_k=top_ks[i],
-                                         multi_probe=multi_probe, key=k_i,
-                                         mode="legacy")[0]
-                per_client.append(None)
+        with span("serve.plan.encrypt"):
+            for i in range(len(query_embs)):
+                k_i = jax.random.fold_in(key, i)
+                try:
+                    qs, st = bp.client.query(k_i,
+                                             [int(c) for c in orders[i]])
+                    per_client.append((qs, st))
+                except PlacementError:
+                    fallback[i] = self.query(
+                        query_embs[i], top_k=top_ks[i],
+                        multi_probe=multi_probe, key=k_i, mode="legacy")[0]
+                    per_client.append(None)
 
         live = [i for i, pc in enumerate(per_client) if pc is not None]
         answers: list = []
-        if live:
-            stacked = jnp.stack([per_client[i][0] for i in live], axis=2)
-            answers = bp.server.answer_batch(stacked)   # per bucket (m_b, C)
+        with span("serve.plan.dispatch"):
+            if live:
+                stacked = jnp.stack([per_client[i][0] for i in live], axis=2)
+                answers = bp.server.answer_batch(stacked)  # (m_b, C) each
         # plan-time decode snapshot (shallow list copies pin the epoch's
         # hint/config ARRAYS; commits replace list elements, never mutate)
         hints = list(bp.client.hints)

@@ -19,9 +19,6 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.modmatmul import modmatmul_pallas
-# Zero-overhead profiler regions: a no-op context unless
-# repro.obs.trace.enable_kernel_annotations(True) is in effect.
-from repro.obs.trace import kernel_annotation
 
 U32 = jnp.uint32
 
@@ -159,8 +156,7 @@ def modmatmul(db: jax.Array, q: jax.Array, *, impl: str = "auto",
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
 
     if impl == "xla":
-        with kernel_annotation("pirrag.modmatmul.xla"):
-            out = _modmatmul_ref_jit(db, q2)
+        out = _modmatmul_ref_jit(db, q2)
     elif impl == "pallas":
         bm, bn, bb = block
         # Hot-loop reuse: the serving DB is the same array object across
@@ -173,9 +169,8 @@ def modmatmul(db: jax.Array, q: jax.Array, *, impl: str = "auto",
                _db_pad_cache.get((id(db), db.shape, bn), (db,), pad))
         qp = _pad_to(_pad_to(q2, 0, bn), 1, bb)
         interpret = jax.default_backend() != "tpu"
-        with kernel_annotation("pirrag.modmatmul.pallas"):
-            out = modmatmul_pallas(dbp, qp, bm=bm, bn=bn, bb=bb,
-                                   interpret=interpret)
+        out = modmatmul_pallas(dbp, qp, bm=bm, bn=bn, bb=bb,
+                               interpret=interpret)
         out = out[:, :q2.shape[1]]
     else:
         raise ValueError(f"unknown impl {impl!r}")
@@ -206,12 +201,10 @@ def delta_gemm(new_cols: jax.Array, old_cols: jax.Array, a_j: jax.Array, *,
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     if impl == "xla":
-        with kernel_annotation("pirrag.delta_gemm.xla"):
-            diff = new_cols.astype(U32) - old_cols.astype(U32)
-            return ref.modmatmul_ref(diff, a_j)
-    with kernel_annotation("pirrag.delta_gemm.pallas"):
-        return (modmatmul(new_cols, a_j, impl=impl)
-                - modmatmul(old_cols, a_j, impl=impl))
+        diff = new_cols.astype(U32) - old_cols.astype(U32)
+        return ref.modmatmul_ref(diff, a_j)
+    return (modmatmul(new_cols, a_j, impl=impl)
+            - modmatmul(old_cols, a_j, impl=impl))
 
 
 @jax.jit
@@ -320,8 +313,7 @@ def bucketed_modmatmul(dbs: Sequence[jax.Array], qs: jax.Array, *,
         # one (m_b, W) @ (W, C) call per bucket — C stacked client columns
         # share the dispatch, each output column the same exact u32 dot as
         # the old per-column matvec loop (parity-tested bitwise)
-        with kernel_annotation("pirrag.bucketed_modmatmul.xla"):
-            out = [_matvec_u32(d, q3[b]) for b, d in enumerate(dbs)]
+        out = [_matvec_u32(d, q3[b]) for b, d in enumerate(dbs)]
     elif impl == "pallas":
         bm, bn, bb = block
         m_pad = max(d.shape[0] for d in dbs)
@@ -336,9 +328,8 @@ def bucketed_modmatmul(dbs: Sequence[jax.Array], qs: jax.Array, *,
                      tuple(dbs), restack))
         qp = _pad_to(_pad_to(q3, 1, bn), 2, bb)
         interpret = jax.default_backend() != "tpu"
-        with kernel_annotation("pirrag.bucketed_modmatmul.pallas"):
-            full = jax.vmap(lambda d, q: modmatmul_pallas(
-                d, q, bm=bm, bn=bn, bb=bb, interpret=interpret))(stack, qp)
+        full = jax.vmap(lambda d, q: modmatmul_pallas(
+            d, q, bm=bm, bn=bn, bb=bb, interpret=interpret))(stack, qp)
         out = [full[b, :d.shape[0], :q3.shape[2]] for b, d in enumerate(dbs)]
     else:
         raise ValueError(f"unknown impl {impl!r}")
@@ -364,8 +355,7 @@ def kmeans_assign(x: jax.Array, c: jax.Array, *, impl: str = "auto",
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     if impl == "xla":
-        with kernel_annotation("pirrag.kmeans_assign.xla"):
-            return ref.kmeans_assign_ref(x, c)
+        return ref.kmeans_assign_ref(x, c)
     from repro.kernels.kmeans_assign import kmeans_assign_pallas
     bn, bk = block
     n, k = x.shape[0], c.shape[0]
@@ -376,7 +366,6 @@ def kmeans_assign(x: jax.Array, c: jax.Array, *, impl: str = "auto",
         pad = cp.shape[0] - k
         cp = cp.at[k:].set(jnp.full((pad, c.shape[1]), 1e30, c.dtype))
     interpret = jax.default_backend() != "tpu"
-    with kernel_annotation("pirrag.kmeans_assign.pallas"):
-        assign, d2 = kmeans_assign_pallas(xp, cp, bn=bn, bk=bk,
-                                          interpret=interpret)
+    assign, d2 = kmeans_assign_pallas(xp, cp, bn=bn, bk=bk,
+                                      interpret=interpret)
     return assign[:n], d2[:n]

@@ -12,8 +12,8 @@ One cross-cutting layer (ISSUE 7), three modules:
             deterministic (no clock reads), associatively mergeable across
             shards, sharing ONE percentile rank rule with `traffic.slo`.
 `trace`     `Tracer`/`Span` nested spans with explicit parent ids,
-            Chrome-trace/Perfetto export, and the zero-overhead-when-
-            disabled `kernel_annotation` hook `repro.kernels.ops` wears.
+            Chrome-trace/Perfetto export, each span mirrored into the JAX
+            profiler's trace as a `TraceAnnotation`.
 
 `Obs` bundles a tracer and a registry behind one handle the serving stack
 threads through itself: the serve engines open tick/plan/gemm/complete
@@ -33,17 +33,14 @@ from repro.obs.registry import (DEFAULT_MS_BUCKETS, DEFAULT_SIZE_BUCKETS,
                                 Counter, Gauge, Histogram, MetricsRegistry,
                                 percentile)
 from repro.obs.scrub import PrivacyViolation, register_enum, scrub
-from repro.obs.trace import (Span, Tracer, enable_kernel_annotations,
-                             kernel_annotation, kernel_annotations_enabled,
-                             span_coverage, validate_chrome_trace)
+from repro.obs.trace import (Span, Tracer, span_coverage,
+                             validate_chrome_trace)
 
 __all__ = [
     "Obs", "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "DEFAULT_MS_BUCKETS", "DEFAULT_SIZE_BUCKETS", "percentile",
     "PrivacyViolation", "register_enum", "scrub",
     "Span", "Tracer", "span_coverage", "validate_chrome_trace",
-    "enable_kernel_annotations", "kernel_annotation",
-    "kernel_annotations_enabled",
 ]
 
 
@@ -61,9 +58,12 @@ class Obs:
         self.tracer = Tracer(clock=clock, keep=trace)
         self.metrics = MetricsRegistry()
 
-    def span(self, name: str, **attrs) -> Span:
-        """Open a nested span (context manager); attrs are scrubbed."""
-        return self.tracer.span(name, **attrs)
+    def span(self, name: str, *, mirror: bool = True, **attrs) -> Span:
+        """Open a nested span (context manager); attrs are scrubbed.
+
+        ``mirror=False`` keeps it out of the profiler's trace.
+        """
+        return self.tracer.span(name, mirror=mirror, **attrs)
 
     def instant(self, name: str, **attrs) -> None:
         """Record a point-in-time event (no-op when tracing is off)."""

@@ -20,19 +20,23 @@ point-in-time markers, timestamps in microseconds.  `validate_chrome_trace`
 structurally checks an export (the CI gate re-checks the privacy allowlist
 on every ``args`` value too — `scripts/check_trace.py`).
 
-Kernel regions: `kernel_annotation(name)` returns a
-`jax.profiler.TraceAnnotation` context only while
-`enable_kernel_annotations(True)` is in effect, and a shared no-op context
-otherwise — the hot kernel wrappers in `repro.kernels.ops` wear it with
-zero overhead when disabled (one global-bool check, no profiler import).
+Profiler mirroring: a span also opens a `jax.profiler.TraceAnnotation`
+under its own name, with its integer attributes as metadata, and closes it
+on exit — so the spans land on the profiler's clock beside the device's
+programs whenever a profiler session is recording.  With no session that
+is one TraceMe enter/exit: no clock read, lock or device sync.  A span
+opened with ``mirror=False`` (the engines' per-tick root, which an idle
+spin would otherwise stamp into the trace thousands of times a second)
+stays out of the profiler.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import json
 import time
 from typing import Callable
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.scrub import scrub
 
@@ -47,6 +51,8 @@ class Span:
     t1: float | None = None
     attrs: dict = dataclasses.field(default_factory=dict)
     _tracer: "Tracer | None" = dataclasses.field(
+        default=None, repr=False, compare=False)
+    _mirror: TraceAnnotation | None = dataclasses.field(
         default=None, repr=False, compare=False)
 
     @property
@@ -67,6 +73,9 @@ class Span:
         tracer = self._tracer
         assert tracer is not None, "span already closed"
         self.t1 = tracer.clock()
+        if self._mirror is not None:
+            self._mirror.__exit__(exc_type, exc, tb)
+            self._mirror = None
         self._tracer = None
         tracer._close(self)
 
@@ -89,14 +98,21 @@ class Tracer:
         self._stack: list[int] = []      # open span ids (nesting)
         self._next_sid = 0
 
-    def span(self, name: str, **attrs) -> Span:
-        """Open a nested span (use as a context manager)."""
+    def span(self, name: str, *, mirror: bool = True, **attrs) -> Span:
+        """Open a nested span (use as a context manager).
+
+        ``mirror`` also opens it in the profiler (see the module doc).
+        """
         sid, self._next_sid = self._next_sid, self._next_sid + 1
         sp = Span(name=name, sid=sid,
                   parent=self._stack[-1] if self._stack else None,
                   t0=self.clock(), _tracer=self)
         if attrs:
             sp.set(**attrs)
+        if mirror:
+            sp._mirror = TraceAnnotation(name, **{
+                k: v for k, v in sp.attrs.items() if type(v) is int})
+            sp._mirror.__enter__()
         self._stack.append(sid)
         return sp
 
@@ -210,34 +226,3 @@ def validate_chrome_trace(obj) -> list[str]:
         if e.get("ph") not in ("X", "i", "M"):
             errs.append(f"event {i}: unknown phase {e.get('ph')!r}")
     return errs
-
-
-# -- kernel-region annotations (zero overhead when disabled) -----------------
-
-_KERNEL_ANNOTATIONS = False
-_NULL_CTX = contextlib.nullcontext()
-
-
-def enable_kernel_annotations(on: bool = True) -> None:
-    """Toggle `jax.profiler.TraceAnnotation` wrapping of kernel regions.
-
-    Off (the default), `kernel_annotation` returns a shared no-op context:
-    the hot path pays one global-bool check and nothing else.  On, kernel
-    dispatches in `repro.kernels.ops` appear as named regions in JAX
-    profiler traces (TensorBoard / Perfetto).
-    """
-    global _KERNEL_ANNOTATIONS
-    _KERNEL_ANNOTATIONS = bool(on)
-
-
-def kernel_annotations_enabled() -> bool:
-    """Whether kernel-region profiler annotations are currently on."""
-    return _KERNEL_ANNOTATIONS
-
-
-def kernel_annotation(name: str):
-    """Context manager naming a kernel region (no-op unless enabled)."""
-    if not _KERNEL_ANNOTATIONS:
-        return _NULL_CTX
-    from jax.profiler import TraceAnnotation
-    return TraceAnnotation(name)

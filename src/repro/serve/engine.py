@@ -77,12 +77,15 @@ class BatchTiming:
     ``t_plan − t_arrival``.  ``encode_s`` is host-side query formulation +
     GEMM enqueue; ``gemm_s`` is the complete-stage wait for device results
     (under the pipelined engine this is the RESIDUAL wait after overlap,
-    often ~0); ``decode_s`` is host-side decode + re-rank.
+    often ~0); ``decode_s`` is host-side decode + re-rank.  ``bid`` is the
+    batch's sequential id in its engine, the ``bid`` attribute of every
+    span the batch opens — the join of its plan and retire spans.
     """
     t_plan: float
     encode_s: float
     gemm_s: float
     decode_s: float
+    bid: int
 
 
 @dataclasses.dataclass(frozen=True)
@@ -253,6 +256,7 @@ class PIRServeLoop:
         self._delayed: list = []      # (ready_t, seq, Request) min-heap
         self._seq = 0                 # heap tiebreak = admission order
         self._tick_no = 0
+        self._bid = 0                 # next dispatched group's batch id
         # Commit-failure retry state: an injected stage failure leaves the
         # journal batch pending; the commit is retried with exponential
         # tick backoff instead of being lost or hammered.
@@ -471,6 +475,11 @@ class PIRServeLoop:
             groups.setdefault(k, []).append(r)
         return [(k, groups[k]) for k in sorted(groups)]
 
+    def _next_bid(self) -> int:
+        """The next dispatched group's batch id (sequential per engine)."""
+        bid, self._bid = self._bid, self._bid + 1
+        return bid
+
     def _plan_group(self, system, kind: tuple[str, int],
                     reqs: list[Request], kq):
         """Encode + dispatch one request group → its `InflightBatch`.
@@ -478,13 +487,16 @@ class PIRServeLoop:
         The one place both engines form batches, so the sync and pipelined
         paths cannot diverge per kind: lookups route through
         `lookup_batch_async` (results are (κ, d) row arrays), queries
-        through `query_batch_async` (results are top-k doc lists)."""
+        through `query_batch_async` (results are top-k doc lists), which
+        opens the plan's pick/encrypt/dispatch spans on this engine's
+        `Obs`."""
         if kind[0] == "lookup":
             return system.lookup_batch_async(
                 [r.lookup_ids for r in reqs], key=kq)
         embs = np.stack([r.query_emb for r in reqs])
         return system.query_batch_async(embs, top_k=[r.top_k for r in reqs],
-                                        multi_probe=kind[1], key=kq)
+                                        multi_probe=kind[1], key=kq,
+                                        obs=self.obs)
 
     def _serving_system(self):
         return self.live.system if self.live is not None else self.system
@@ -502,7 +514,8 @@ class PIRServeLoop:
         the `BatchTiming` components — one timeline, two consumers.
         """
         self._tick_no += 1
-        with self.obs.span("serve.tick", engine=self.ENGINE) as tick_sp:
+        with self.obs.span("serve.tick", mirror=False,
+                           engine=self.ENGINE) as tick_sp:
             self.obs.gauge("serve.queue_depth").set(self.batcher.depth)
             self._commit_mutations()
             now = self.clock()
@@ -523,18 +536,20 @@ class PIRServeLoop:
                 # query_batch ≡ query_batch_async().complete(); the async
                 # form only adds the component span boundaries — responses
                 # stay bit-identical to the one-call path
+                bid = self._next_bid()
                 with self.obs.span("serve.plan", batch=len(reqs),
-                                   kind=kind[0],
-                                   multi_probe=kind[1]) as sp_plan:
+                                   kind=kind[0], multi_probe=kind[1],
+                                   bid=bid) as sp_plan:
                     infl = self._plan_group(system, kind, reqs, kq)
-                with self.obs.span("serve.gemm", batch=len(reqs)) as sp_gemm:
+                with self.obs.span("serve.gemm", batch=len(reqs),
+                                   bid=bid) as sp_gemm:
                     jax.block_until_ready(infl.pending)
-                with self.obs.span("serve.complete",
-                                   batch=len(reqs)) as sp_done:
+                with self.obs.span("serve.complete", batch=len(reqs),
+                                   bid=bid) as sp_done:
                     results = infl.complete()
                 self._record(reqs, results, cur, sp_done.t1, BatchTiming(
                     t_plan=sp_plan.t0, encode_s=sp_plan.dur,
-                    gemm_s=sp_gemm.dur, decode_s=sp_done.dur))
+                    gemm_s=sp_gemm.dur, decode_s=sp_done.dur, bid=bid))
             return len(fresh)
 
     def _generate_dispatch(self, reqs: list[Request], results: list):
@@ -722,7 +737,8 @@ class PipelinedServeLoop(PIRServeLoop):
         tick than its plan span — the pipeline overlap made visible).
         """
         self._tick_no += 1
-        with self.obs.span("serve.tick", engine=self.ENGINE) as tick_sp:
+        with self.obs.span("serve.tick", mirror=False,
+                           engine=self.ENGINE) as tick_sp:
             self.obs.gauge("serve.queue_depth").set(self.batcher.depth)
             self._commit_mutations()
             now = self.clock()
@@ -744,12 +760,13 @@ class PipelinedServeLoop(PIRServeLoop):
             system = self._serving_system()
             for kind, reqs in self._probe_groups(fresh):
                 self._key, kq = jax.random.split(self._key)
+                bid = self._next_bid()
                 with self.obs.span("serve.plan", batch=len(reqs),
-                                   kind=kind[0],
-                                   multi_probe=kind[1]) as sp_plan:
+                                   kind=kind[0], multi_probe=kind[1],
+                                   bid=bid) as sp_plan:
                     infl = self._plan_group(system, kind, reqs, kq)
                 self._inflight.append((reqs, cur, infl, sp_plan.t0,
-                                       sp_plan.dur))
+                                       sp_plan.dur, bid))
             self.obs.gauge("serve.inflight").set(len(self._inflight))
             self._retire(self.depth)
             return len(fresh)
@@ -814,14 +831,17 @@ class PipelinedServeLoop(PIRServeLoop):
         """
         n_parked = len(self._gen_pending)
         while len(self._inflight) > limit:
-            reqs, epoch, infl, t_plan, encode_s = self._inflight.popleft()
-            with self.obs.span("serve.gemm", batch=len(reqs)) as sp_gemm:
+            (reqs, epoch, infl, t_plan, encode_s,
+             bid) = self._inflight.popleft()
+            with self.obs.span("serve.gemm", batch=len(reqs),
+                               bid=bid) as sp_gemm:
                 jax.block_until_ready(infl.pending)
-            with self.obs.span("serve.complete", batch=len(reqs)) as sp_done:
+            with self.obs.span("serve.complete", batch=len(reqs),
+                               bid=bid) as sp_done:
                 results = infl.complete()
             self._record(reqs, results, epoch, sp_done.t1, BatchTiming(
                 t_plan=t_plan, encode_s=encode_s, gemm_s=sp_gemm.dur,
-                decode_s=sp_done.dur))
+                decode_s=sp_done.dur, bid=bid))
         while n_parked >= self.gen_coalesce:
             self._retire_gen(self.gen_coalesce)
             n_parked -= self.gen_coalesce

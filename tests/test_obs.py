@@ -13,8 +13,12 @@ Three layers under test:
     byte-identical exports) and correctly nested across the pipelined
     engine's in-flight depth; a full serve-loop export contains zero
     query-derived payload bytes (the audit greps the serialized JSON).
+  * profiler mirroring — the engine's spans, read back from a CPU profiler
+    trace, carry one batch id per batch across its plan and retire spans;
+    idle ticks leave nothing there; a profiler session changes no response.
 """
 import copy
+import glob
 import json
 
 import numpy as np
@@ -26,7 +30,6 @@ from test_serve_engine import (FakeClock, N_DOCS, _drive_scripted,
 from repro.obs import (Histogram, MetricsRegistry, Obs, PrivacyViolation,
                        Span, Tracer, percentile, scrub, span_coverage,
                        validate_chrome_trace)
-from repro.obs import trace as trace_mod
 from repro.serve import PipelinedServeLoop
 from repro.traffic.slo import _pct
 
@@ -229,18 +232,6 @@ def test_validate_chrome_trace():
     assert validate_chrome_trace([1, 2]) == ["top level must be an object"]
 
 
-def test_kernel_annotation_zero_overhead_when_disabled():
-    assert not trace_mod.kernel_annotations_enabled()
-    ctx = trace_mod.kernel_annotation("pirrag.modmatmul.xla")
-    assert ctx is trace_mod.kernel_annotation("other")   # shared no-op
-    trace_mod.enable_kernel_annotations(True)
-    try:
-        from jax.profiler import TraceAnnotation
-        assert isinstance(trace_mod.kernel_annotation("k"), TraceAnnotation)
-    finally:
-        trace_mod.enable_kernel_annotations(False)
-
-
 # -- the serve loop under trace: nesting, determinism, privacy ---------------
 
 def _traced_loop(base, *, depth=2, trace=True):
@@ -409,3 +400,107 @@ def test_commit_spans_and_counters(base_live):
 @pytest.fixture(scope="module")
 def base_live():
     return _get_base()
+
+
+# -- the spans mirrored into the profiler's trace -----------------------------
+
+MIRRORED = ("serve.plan", "serve.plan.pick", "serve.plan.encrypt",
+            "serve.plan.dispatch", "serve.gemm", "serve.complete",
+            "serve.complete.fetch")
+
+
+def _drive_profiled(base, corp, ops, log_dir=None):
+    """Run `ops` through a pipelined loop, under a CPU profiler session when
+    ``log_dir`` is given: then idle ticks follow the drain, inside a
+    ``test.idle`` annotation.  Returns (loop, host events or None); each
+    event is (name, start_ns, end_ns, stats, thread line)."""
+    import jax
+    from jax.profiler import ProfileData, TraceAnnotation
+    loop, _ = _traced_loop(base, trace=False)
+    if log_dir is None:
+        _drive_scripted(loop, corp, ops)
+        return loop, None
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        _drive_scripted(loop, corp, ops)
+        with TraceAnnotation("test.idle"):
+            for _ in range(50):
+                assert loop.tick() == 0
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    events = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+               dict(e.stats), line.name)
+              for plane in ProfileData.from_file(path).planes
+              if plane.name == "/host:CPU"
+              for line in plane.lines for e in line.events]
+    return loop, events
+
+
+@pytest.fixture(scope="module")
+def profiled(base_live, tmp_path_factory):
+    """The same scripted schedule (single- and multi-probe batches,
+    mutations) with and without a profiler session recording."""
+    corp, base = base_live
+    ops = _script_from_rng(np.random.default_rng(23), 40) + [("drain",)]
+    loop, events = _drive_profiled(base, corp, ops,
+                                   tmp_path_factory.mktemp("profile"))
+    plain, _ = _drive_profiled(base, corp, ops)
+    return loop, events, plain
+
+
+def test_profiler_trace_holds_every_mirrored_span(profiled):
+    loop, events, _ = profiled
+    names = {e[0] for e in events}
+    assert set(MIRRORED) <= names
+    assert "serve.tick" not in names           # the spin root stays out
+    assert not any(n.startswith("bench.") for n in names)
+    # each plan holds exactly one pick, one encrypt and one dispatch
+    for name, t0, t1, _, line in events:
+        if name == "serve.plan":
+            inner = [e[0] for e in events if e[4] == line
+                     and t0 <= e[1] and e[2] <= t1
+                     and e[0].startswith("serve.plan.")]
+            assert sorted(inner) == ["serve.plan.dispatch",
+                                     "serve.plan.encrypt",
+                                     "serve.plan.pick"]
+
+
+def test_profiler_spans_join_by_batch_id(profiled):
+    """A batch's plan (under the tick that dispatched it) and its gemm and
+    complete (under the tick that retired it) carry one `bid`, once per
+    stage, and it is the `bid` of the batch's BatchTiming."""
+    loop, events, _ = profiled
+    bids = {}
+    for stage in ("serve.plan", "serve.gemm", "serve.complete"):
+        bids[stage] = [e[3]["bid"] for e in events if e[0] == stage]
+        assert len(bids[stage]) == len(set(bids[stage])) > 3
+    assert set(bids["serve.plan"]) == set(bids["serve.gemm"]) \
+        == set(bids["serve.complete"])
+    served = {r.timing.bid for r in loop.responses if r.timing is not None}
+    assert served == set(bids["serve.plan"])
+    batch = {e[3]["bid"]: e[3]["batch"] for e in events
+             if e[0] == "serve.plan"}
+    for r in loop.responses:
+        assert batch[r.timing.bid] == r.batch_size
+
+
+def test_idle_ticks_leave_no_profiler_event(profiled):
+    _, events, _ = profiled
+    (_, t0, t1, _, line), = [e for e in events if e[0] == "test.idle"]
+    assert [e for e in events if e[4] == line and t0 <= e[1] <= t1
+            and e[0] != "test.idle"] == []
+
+
+def test_profiler_session_changes_no_response(profiled):
+    loop, _, plain = profiled
+    assert loop.responses, "the comparison needs a real run"
+
+    def fields(lp):
+        return [(r.rid, r.top, r.t_done, r.batch_size, r.epoch, r.retries,
+                 r.timing) for r in lp.responses]
+
+    assert fields(loop) == fields(plain)
