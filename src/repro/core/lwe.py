@@ -142,6 +142,30 @@ def encrypt_vector(key: jax.Array, s: jax.Array, a_mat: jax.Array,
     return mask + e + jnp.uint32(delta) * msg.astype(U32)
 
 
+@jax.jit
+def encrypt_onehots(key: jax.Array, a_mat: jax.Array, indices: jax.Array,
+                    delta: jnp.uint32,
+                    sigma: float) -> tuple[jax.Array, jax.Array]:
+    """C one-hot encryptions in one program: (cts (n, C), secrets (k, C)).
+
+    Column i is the one-hot of ``indices[i]`` under ``fold_in(key, i)``,
+    split into a `keygen` key and an `encrypt_vector` error key, so it is
+    bit-identical to that per-query chain: each key's random bits do not
+    depend on the batch, and the (n, k)·(k, C) product is exact mod 2^32.
+    """
+    n, k = a_mat.shape
+
+    def secret_and_error(i):
+        k_sec, k_err = jax.random.split(jax.random.fold_in(key, i))
+        return (jax.random.bits(k_sec, (k,), dtype=U32),     # as `keygen`
+                sample_error(k_err, (n,), sigma))
+
+    s, e = jax.vmap(secret_and_error, out_axes=1)(
+        jnp.arange(indices.shape[0]))
+    onehots = (jnp.arange(n)[:, None] == indices[None, :]).astype(U32)
+    return jnp.matmul(a_mat, s) + e + jnp.uint32(delta) * onehots, s
+
+
 def hint_strip(ans: jax.Array, hint: jax.Array, s: jax.Array) -> jax.Array:
     """ans − H·s (mod q): leaves Δ·(D·msg) + D·e."""
     return ans - jnp.matmul(hint, s.astype(U32))

@@ -574,10 +574,13 @@ class PirRagSystem:
         With an `Obs` (``repro.obs``), the plan's stages open spans on it:
         ``serve.plan.pick`` (the cluster pick, whose distances come back
         to the host — it waits for the device work queued ahead of it),
-        ``serve.plan.encrypt`` (the client's A and every LWE encrypt) and
+        ``serve.plan.encrypt`` (every LWE encrypt; the legacy path's are
+        one device program, `PIRClient.query_batch`) and
         ``serve.plan.dispatch`` (answer and decode enqueued); the legacy
         path's `complete()` opens ``serve.complete.fetch`` around its one
-        device-to-host copy.
+        device-to-host copy.  The legacy path also counts its encrypts on
+        the `Obs`: ``serve.encrypt.programs`` (one per batch) and
+        ``serve.encrypt.batched_queries`` (B·P per batch).
         """
         span = obs.span if obs is not None else _no_span
         if key is None:
@@ -603,23 +606,22 @@ class PirRagSystem:
                 jnp.asarray(self.centroids)))
             orders = np.argsort(d2, axis=1)[:, :p]           # (B, P)
         with span("serve.plan.encrypt"):
-            # the client object snapshots cfg + hint at THIS epoch
-            client = pir.PIRClient(self.cfg, self.hint)
-            qs, states = [], []
-            for b in range(len(query_embs)):
-                for j, c in enumerate(orders[b]):
-                    qu, st = client.query(
-                        jax.random.fold_in(key, b * p + j), int(c))
-                    qs.append(qu)
-                    states.append(st)
+            # the client object snapshots cfg + hint at THIS epoch; A is
+            # the server's, built once per config
+            client = pir.PIRClient(self.cfg, self.hint,
+                                   a_matrix=self.server.a_matrix)
+            # column b·P + j: request b's j-th probe, key fold_in(key, b·P + j)
+            qs, secrets = client.query_batch(key, orders.reshape(-1))
+            if obs is not None:     # counts only: never an index
+                obs.counter("serve.encrypt.programs").inc()
+                obs.counter("serve.encrypt.batched_queries").inc(orders.size)
         # dispatch: enqueue the GEMM AND the batched recover — the whole
         # answer→plaintext chain rides the device stream, so `complete`
         # is pure host work (one ready-array fetch + parse + rerank) and
         # never queues behind other in-flight device chains
         with span("serve.plan.dispatch"):
-            ans = self.server.answer(jnp.stack(qs, axis=1))  # (m, B·P)
-            cols = client.recover_batch(
-                ans, jnp.stack([st.secret for st in states], axis=1))
+            ans = self.server.answer(qs)                     # (m, B·P)
+            cols = client.recover_batch(ans, secrets)
 
         def complete():
             with span("serve.complete.fetch"):

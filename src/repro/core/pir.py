@@ -360,13 +360,22 @@ class ClientQueryState:
 
 
 class PIRClient:
-    """Client side: query formulation and response decoding."""
+    """Client side: query formulation and response decoding.
 
-    def __init__(self, cfg: PIRConfig, hint: jax.Array):
+    ``a_matrix`` is the public LWE matrix A for ``cfg``, e.g. the server's
+    cached `PIRServer.a_matrix` (A depends only on ``a_seed``, n and k, so
+    commits never change it); without one the client derives A itself.
+    """
+
+    def __init__(self, cfg: PIRConfig, hint: jax.Array,
+                 a_matrix: jax.Array | None = None):
         assert hint.shape == (cfg.m, cfg.params.k)
         self.cfg = cfg
         self.hint = hint
-        self._a_mat = lwe.gen_public_matrix(cfg.a_seed, cfg.n, cfg.params.k)
+        if a_matrix is None:
+            a_matrix = lwe.gen_public_matrix(cfg.a_seed, cfg.n, cfg.params.k)
+        assert a_matrix.shape == (cfg.n, cfg.params.k), a_matrix.shape
+        self._a_mat = a_matrix
 
     def query(self, key: jax.Array, index: int) -> tuple[jax.Array,
                                                           ClientQueryState]:
@@ -377,6 +386,19 @@ class PIRClient:
         qu = lwe.encrypt_vector(k_err, s, self._a_mat, onehot,
                                 self.cfg.params.delta, self.cfg.params.sigma)
         return qu, ClientQueryState(secret=s, index=index)
+
+    def query_batch(self, key: jax.Array, indices
+                    ) -> tuple[jax.Array, jax.Array]:
+        """Encrypt C one-hot selectors in one device program.
+
+        Returns (qs (n, C) u32, secrets (k, C) u32); column i is
+        bit-identical to ``query(fold_in(key, i), indices[i])`` — the
+        ciphertext and its state's secret.  One compiled program per C.
+        """
+        p = self.cfg.params
+        return lwe.encrypt_onehots(key, self._a_mat,
+                                   np.asarray(indices, np.int32),
+                                   np.uint32(p.delta), p.sigma)
 
     def recover(self, ans: jax.Array, state: ClientQueryState) -> jax.Array:
         """Decode the server answer into the plaintext column (m,) u8."""

@@ -71,6 +71,74 @@ def test_batched_matches_sequential(system_and_corpus):
         assert [d for d, _, _ in res] == [d for d, _, _ in solo]
 
 
+def _per_query_loop(sys, embs, p, key, top_k):
+    """The legacy plan as one `PIRClient.query` per (request, probe):
+    its decoded (m, B·P) columns and its top-k lists."""
+    from repro.core import chunking, clustering, pir, rerank
+    import jax.numpy as jnp
+    d2 = np.asarray(clustering.pairwise_sqdist(
+        jnp.asarray(embs, jnp.float32), jnp.asarray(sys.centroids)))
+    orders = np.argsort(d2, axis=1)[:, :p]
+    client = pir.PIRClient(sys.cfg, sys.hint)
+    qs, states = zip(*[client.query(jax.random.fold_in(key, b * p + j), int(c))
+                       for b in range(len(embs))
+                       for j, c in enumerate(orders[b])])
+    cols = np.asarray(client.recover_batch(
+        sys.server.answer(jnp.stack(qs, axis=1)),
+        jnp.stack([st.secret for st in states], axis=1)))
+    tops = []
+    for b in range(len(embs)):
+        docs = [d for j in range(p) for d in chunking.deserialize_docs(
+            cols[:, b * p + j], sys.db.emb_dim)]
+        tops.append(rerank.rerank(np.asarray(embs[b], np.float32), docs,
+                                  top_k))
+    return cols, tops
+
+
+@pytest.mark.parametrize("multi_probe", [1, 3])
+def test_batched_encrypt_serves_as_per_query_loop(system_and_corpus,
+                                                  multi_probe):
+    """One encrypt program per batch: same decoded columns, same top-k."""
+    sys, corp = system_and_corpus
+    embs = corp.embeddings[[3, 50, 120, 201]]
+    key = jax.random.PRNGKey(31)
+    infl = sys.query_batch_async(embs, top_k=4, multi_probe=multi_probe,
+                                 key=key)
+    cols, tops = _per_query_loop(sys, embs, multi_probe, key, 4)
+    assert infl.pending[0].shape == (sys.db.m, len(embs) * multi_probe)
+    np.testing.assert_array_equal(np.asarray(infl.pending[0]), cols)
+    assert infl.complete() == tops
+
+
+def test_batched_encrypt_counts_and_reuses_a(system_and_corpus, monkeypatch):
+    """Obs counters read one program and B·P queries a batch; every batch
+    encrypts under the server's one A, which is never regenerated."""
+    from repro.core import lwe, pir
+    from repro.obs import Obs
+    sys, corp = system_and_corpus
+    a_seen = []
+    query_batch = pir.PIRClient.query_batch
+
+    def spy(self, key, indices):
+        a_seen.append(self._a_mat)
+        return query_batch(self, key, indices)
+
+    def no_regen(*args):
+        raise AssertionError("A regenerated for a serving batch")
+
+    monkeypatch.setattr(pir.PIRClient, "query_batch", spy)
+    monkeypatch.setattr(lwe, "gen_public_matrix", no_regen)
+    obs = Obs()
+    for b, p in [(3, 1), (2, 3)]:
+        sys.query_batch_async(corp.embeddings[:b], top_k=2, multi_probe=p,
+                              key=jax.random.PRNGKey(b), obs=obs).complete()
+    m = obs.metrics_dict()
+    assert m["serve.encrypt.programs"] == 2
+    assert m["serve.encrypt.batched_queries"] == 3 * 1 + 2 * 3
+    assert len(a_seen) == 2
+    assert a_seen[0] is a_seen[1] is sys.server.a_matrix
+
+
 def test_build_seed_streams_are_independent():
     """One build seed, TWO independent fold_in streams.
 

@@ -49,6 +49,35 @@ def test_batched_answers_match_individual():
         np.testing.assert_array_equal(np.asarray(got), np.asarray(db[:, idx]))
 
 
+@pytest.mark.parametrize("c", [1, 5, 16])
+def test_query_batch_bit_identical_to_per_query_loop(c):
+    """One-program encrypt == stacked query(fold_in(key, i), idx[i])."""
+    db, cfg, server, client = _setup()
+    key = jax.random.PRNGKey(2024)
+    idx = np.random.default_rng(c).integers(0, cfg.n, c)
+    qs, secrets = client.query_batch(key, idx)
+    want = [client.query(jax.random.fold_in(key, i), int(j))
+            for i, j in enumerate(idx)]
+    assert qs.shape == (cfg.n, c) and qs.dtype == jnp.uint32
+    assert secrets.shape == (cfg.params.k, c) and secrets.dtype == jnp.uint32
+    np.testing.assert_array_equal(
+        np.asarray(qs), np.stack([np.asarray(q) for q, _ in want], axis=1))
+    np.testing.assert_array_equal(
+        np.asarray(secrets),
+        np.stack([np.asarray(st.secret) for _, st in want], axis=1))
+    got = client.recover_batch(server.answer(qs), secrets)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(db)[:, idx])
+
+
+def test_client_takes_the_servers_public_matrix():
+    db, cfg, server, _ = _setup()
+    client = pir.PIRClient(cfg, server.setup(), a_matrix=server.a_matrix)
+    assert client._a_mat is server.a_matrix
+    np.testing.assert_array_equal(
+        np.asarray(server.a_matrix),
+        np.asarray(lwe.gen_public_matrix(cfg.a_seed, cfg.n, cfg.params.k)))
+
+
 def test_uplink_downlink_accounting():
     _, cfg, _, _ = _setup(m=1000, n=256)
     assert cfg.uplink_bytes == 256 * 4

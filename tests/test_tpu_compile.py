@@ -95,6 +95,18 @@ def test_client_decode_compiles(one_chip):
     assert "ENTRY" in text
 
 
+@pytest.mark.parametrize("c", [1, 16])
+def test_client_batched_encrypt_compiles(one_chip, c):
+    """A serving batch's C encrypts at 4096 clusters, LWE k = 1024: one
+    program with one u32 (n, k)·(k, C) matmul."""
+    text = _compile(lwe.encrypt_onehots, _sds((2,), jnp.uint32, one_chip),
+                    _sds((4096, 1024), jnp.uint32, one_chip),
+                    _sds((c,), jnp.int32, one_chip),
+                    _sds((), jnp.uint32, one_chip),
+                    _sds((), jnp.float32, one_chip))
+    assert "ENTRY" in text
+
+
 def test_row_shard_gemm_compiles_without_collectives(topo, on_tpu):
     """Each chip's row slice ends in a partial kernel row tile, as a packed
     DB's m/4 usually does: the kernel takes it in place."""
