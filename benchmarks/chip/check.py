@@ -22,10 +22,17 @@ Every number compared is a count of disagreements with the plain reference
 * ``lwe_k_differs``: 1 if the program runs another LWE secret dimension
   than the configuration states;
 * ``answer_kernel_absent`` (on a TPU): 1 if the answer program the server
-  dispatches holds no ``tpu_custom_call`` (the Pallas kernel).
+  dispatches holds no ``tpu_custom_call`` (the Pallas kernel).  On one
+  chip that is ``ops.modmatmul`` over the DB; on a row-sharded DB it is the
+  server's own compiled ``shard_map`` (``PIRServer._answer_fn``) over its
+  sharded DB and a replicated (n, max_batch) query;
+* ``answer_collectives`` (row-sharded DB only): all-gathers, all-reduces,
+  reduce-scatters, collective-permutes and all-to-alls in that compiled
+  program.  Each chip answers its own rows, so the deployment claims none.
 """
 from __future__ import annotations
 
+import re
 import sys
 import time
 
@@ -35,6 +42,10 @@ import reference
 
 ANSWER_ROWS = 2048
 TOPK_SAMPLE = 64
+#: A collective instruction in compiled HLO text (an async one counts once,
+#: at its ``-start``).
+COLLECTIVE = re.compile(r"\b(all-gather|all-reduce|reduce-scatter|"
+                        r"collective-permute|all-to-all)(-start)?\(")
 
 
 def log(msg: str) -> None:
@@ -101,19 +112,29 @@ def compare(cell, run, tap, system, corp, queries, seed: int, *,
                                   != cell.config["lwe_k"])
     log(f"compared {n_words} answer words, {n_bytes} cluster bytes, "
         f"{n_topk} top-k lists in {time.perf_counter() - t:.1f}s")
-    if on_tpu:
+    sharded = system.server.mesh is not None
+    if on_tpu or sharded:
         t = time.perf_counter()
-        checks["answer_kernel_absent"] = int(not answer_has_kernel(system))
+        text = answer_program(system, cell.config["engine"]["max_batch"])
+        if on_tpu:
+            checks["answer_kernel_absent"] = int("tpu_custom_call" not in text)
+        if sharded:
+            checks["answer_collectives"] = len(COLLECTIVE.findall(text))
         log(f"answer program inspected in {time.perf_counter() - t:.1f}s")
     return {k: (v, 0) for k, v in checks.items()}
 
 
-def answer_has_kernel(system) -> bool:
-    """Does the answer program the server runs hold the Pallas kernel?"""
+def answer_program(system, max_batch: int) -> str:
+    """The compiled text of the answer program the server runs."""
     import jax
     import jax.numpy as jnp
-    from repro.kernels import ops
-    q = jnp.zeros((system.db.n, 16), jnp.uint32)
-    text = jax.jit(lambda d, x: ops.modmatmul(d, x, impl=system.cfg.impl)
-                   ).lower(system.server.db, q).compile().as_text()
-    return "tpu_custom_call" in text
+    server = system.server
+    if server.mesh is None:
+        from repro.kernels import ops
+        q = jnp.zeros((system.db.n, 16), jnp.uint32)
+        return jax.jit(lambda d, x: ops.modmatmul(d, x, impl=system.cfg.impl)
+                       ).lower(server.db, q).compile().as_text()
+    from jax.sharding import NamedSharding, PartitionSpec
+    q = jax.device_put(jnp.zeros((system.db.n, max_batch), jnp.uint32),
+                       NamedSharding(server.mesh, PartitionSpec()))
+    return server._answer_fn.lower(server.db, q).compile().as_text()

@@ -1,5 +1,6 @@
-"""Mean `serve.plan.encrypt` span per batch: the client's public matrix A
-and one LWE encrypt per query."""
+"""Mean `serve.plan.encrypt` span per batch: the dispatch of the batch's
+one encrypt program (every query's LWE encrypt), under the server's
+cached public matrix A."""
 import serve_spans
 
 

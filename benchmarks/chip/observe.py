@@ -88,41 +88,61 @@ class CompileCounter:
             self.n += 1
 
 
-def lowlimb_answer(shape: tuple[int, int], q_switch: int):
-    """The control: ``f(db, qu)``, the answer computed plainly with each
-    query word's lowest 8-bit limb dropped (three limbs of four),
-    modulus-switched as the server's answer is."""
+def _lowlimb(d, qu, shift: int):
+    """``d · qu`` with each query word's lowest 8-bit limb dropped, in
+    256-row blocks, modulus-switched by ``shift`` bits."""
     import jax
     import jax.numpy as jnp
 
-    m, n = shape
+    m = d.shape[0]
     rows = 256
-    shift = 32 - int(math.log2(q_switch))
+    qm = qu & jnp.uint32(0xFFFFFF00)
+    # blocks sliced in place: a reshape of d[:m - m % rows] would copy d
+    out = jax.lax.map(lambda i: jnp.matmul(jax.lax.dynamic_slice_in_dim(
+        d, i * rows, rows).astype(jnp.uint32), qm),
+        jnp.arange(m // rows)).reshape(-1, qu.shape[1])
+    if m % rows:
+        out = jnp.concatenate([out, jnp.matmul(
+            d[m - m % rows:].astype(jnp.uint32), qm)])
+    half = jnp.uint32(1 << (shift - 1))
+    return ((out + half) >> jnp.uint32(shift)).astype(jnp.uint16)
 
-    @jax.jit
-    def f(d, qu):
-        qm = qu & jnp.uint32(0xFFFFFF00)
-        blocks = d[:m - m % rows].reshape(-1, rows, n)
-        out = jax.lax.map(lambda blk: jnp.matmul(blk.astype(jnp.uint32), qm),
-                          blocks).reshape(-1, qu.shape[1])
-        if m % rows:
-            out = jnp.concatenate([out, jnp.matmul(
-                d[m - m % rows:].astype(jnp.uint32), qm)])
-        half = jnp.uint32(1 << (shift - 1))
-        return ((out + half) >> jnp.uint32(shift)).astype(jnp.uint16)
 
-    return f
+def lowlimb_answer(srv):
+    """The control: ``f(db, qu)``, the answer computed plainly with each
+    query word's lowest 8-bit limb dropped (three limbs of four),
+    modulus-switched as the server's answer is.
+
+    On a row-sharded server it runs shard by shard over the server's own
+    mesh and row axes, each chip on the rows it holds, so the DB is never
+    gathered; the result is cut to the server's ``m`` rows.
+    """
+    import functools
+
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    f = functools.partial(
+        _lowlimb, shift=32 - int(math.log2(srv.cfg.params.q_switch)))
+    if srv.mesh is None:
+        return jax.jit(f)
+    axes, m = srv.mesh_axes, srv.cfg.m
+    per_shard = jax.shard_map(f, mesh=srv.mesh, in_specs=(P(axes, None), P()),
+                              out_specs=P(axes, None))
+    return jax.jit(lambda d, qu: per_shard(d, qu)[:m])
 
 
 def control():
     """``replace`` that puts the control in the answer's place."""
+    import jax
     fns: dict = {}
 
     def replace(orig, qu):
         srv = orig.__self__
         if srv.db.shape not in fns:
-            fns[srv.db.shape] = lowlimb_answer(srv.db.shape,
-                                               srv.cfg.params.q_switch)
+            fns[srv.db.shape] = lowlimb_answer(srv)
+        if srv.mesh is not None:
+            qu = jax.device_put(qu, srv._replicated)
         return fns[srv.db.shape](srv.db, qu)
 
     return replace

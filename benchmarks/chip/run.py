@@ -10,19 +10,22 @@
 
 A cell (``BENCHMARK.json`` → ``workloads``) names a configuration
 (``configs/<config>.json``: corpus and engine sizes) and a traffic mix
-(``traffic/<mix>.json``: arrival rate, top k).  The run generates the
-corpus from ``--seed`` (``corpus.py``), builds the index through the
-program's own entry (``PirRagSystem.build``), wraps it in the production
-engine (``serve.engine.PipelinedServeLoop``), warms every batch size, then
-offers open-loop arrivals for ``--seconds`` and times each
-request from its due time to its reranked passages.  Each metric is read by
-its own file, ``metrics/<name>.py``: the end-to-end ones with
-``--trace 0``; the per-layer ones with ``--trace 1``, which also records a
-profiler trace of the window (``trace_reduce.py``).  After the window the
-run compares what the timed path produced with ``reference.py``
-(``check.py``) and prints each compared number beside its limit, on
-standard error and as the last key of the result.  The last line of
-standard output is one JSON object.
+(``traffic/<mix>.json``: arrival rate, top k) and the chips it runs on.
+The run generates the corpus from ``--seed`` (``corpus.py``), builds the
+index through the program's own entry (``PirRagSystem.build``; a cell on
+several chips builds it row-sharded over a ``("chunks",)`` mesh of them),
+wraps it in the production engine (``serve.engine.PipelinedServeLoop``),
+warms every batch size, then offers open-loop arrivals for ``--seconds``
+and times each request from its due time to its reranked passages.  Each
+metric is read by its own file, ``metrics/<name>.py``: the end-to-end ones
+with ``--trace 0``; the per-layer ones with ``--trace 1``, which also
+records a profiler trace of the window (``trace_reduce.py``).  A reader
+gets the window's `Run`: requests, batches, the engine's spans and
+counters (``Run.obs``), the trace.  After the window the run compares
+what the timed path produced with ``reference.py`` (``check.py``) and
+prints each compared number beside its limit, on standard error and as
+the last key of the result.  The last line of standard output is one
+JSON object.
 
 The run needs a TPU: with none, or with fewer chips than the cell asks
 for, it exits non-zero and prints no result.
@@ -85,6 +88,17 @@ class Cell:
     per_layer: list
 
 
+def check_traffic(traffic: dict) -> None:
+    """Refuse a traffic mix that the run would not serve as it says: the
+    engine is driven with single-probe queries on a Poisson schedule."""
+    if traffic.get("arrival") != "poisson":
+        raise SystemExit(f"traffic arrival {traffic.get('arrival')!r}: "
+                         "only 'poisson' is generated; no result")
+    if traffic.get("multi_probe") != 1:
+        raise SystemExit(f"traffic multi_probe {traffic.get('multi_probe')!r}"
+                         ": only single-probe queries are served; no result")
+
+
 def load_cell(name: str, bench_file: Path = ROOT / "BENCHMARK.json") -> Cell:
     bench = json.loads(bench_file.read_text())
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -95,6 +109,7 @@ def load_cell(name: str, bench_file: Path = ROOT / "BENCHMARK.json") -> Cell:
     config = json.loads((ROOT / cfg["file"]).read_text())
     traffic = json.loads((HERE / "traffic" / f"{w['traffic']}.json")
                          .read_text())
+    check_traffic(traffic)
     e2e = [m for m in bench["end_to_end"]
            if name in m.get("workloads", cells)]
     reporting = {m["name"]: set(m.get("workloads", cells))
@@ -134,6 +149,9 @@ class Run:
     m: int
     n: int
     peaks: dict
+    chips: int                      # chips the DB's rows shard over
+    shard_rows: int                 # DB rows one chip holds (m on one chip)
+    obs: object = None              # the engine's repro.obs.Obs (counters)
     trace: object = None            # trace_reduce.Reduced (--trace 1)
 
     @staticmethod
@@ -206,9 +224,12 @@ class Built:
 
 
 def build(cell: Cell, seed: int) -> Built:
-    """Corpus from the seed → the program's index."""
+    """Corpus from the seed → the program's index, row-sharded over the
+    cell's chips when it has more than one."""
     from repro.core import pipeline
+    from repro.launch.mesh import make_chunk_mesh
     cfg = cell.config
+    mesh = make_chunk_mesh(cell.chips) if cell.chips > 1 else None
     t = time.perf_counter()
     corp = corpus_lib.make_corpus(
         seed, cfg["data_seed"], cfg["n_docs"], emb_dim=cfg["emb_dim"],
@@ -218,11 +239,11 @@ def build(cell: Cell, seed: int) -> Built:
     t = time.perf_counter()
     system = pipeline.PirRagSystem.build(
         corp.texts, corp.embeddings, n_clusters=cfg["n_clusters"],
-        seed=cfg["build_seed"])
+        seed=cfg["build_seed"], mesh=mesh)
     log(f"build: {time.perf_counter() - t:.2f}s; m={system.db.m} "
         f"n={system.db.n} (index {system.index_seconds:.2f}s, hint "
-        f"{system.hint_seconds:.2f}s); DB {system.server.db.nbytes} B, pad "
-        f"{system.db.pad_fraction:.4f}")
+        f"{system.hint_seconds:.2f}s); DB {system.server.db.nbytes} B over "
+        f"{cell.chips} chip(s), pad {system.db.pad_fraction:.4f}")
     return Built(corp, system, cfg["engine"])
 
 
@@ -301,10 +322,14 @@ class ServedPathError(Exception):
         self.result = result
 
 
-def _device(dev) -> dict:
+def _device(devs: list, peaks: list | None = None) -> dict:
+    """The device record; ``memory_peak_bytes`` is the fullest chip's."""
     import jax
-    return {"platform": dev.platform, "kind": dev.device_kind,
-            "count": len(jax.devices()), "memory_peak_bytes": None}
+    known = [p for p in peaks or [] if p is not None]
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(jax.devices()),
+            "memory_peak_bytes": max(known) if known else None,
+            "memory_peak_bytes_per_chip": peaks}
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
@@ -317,6 +342,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     """
     import jax
     dev = require_device(cell.chips) if require_tpu else jax.devices()[0]
+    devs = jax.devices()[:cell.chips]      # the build's mesh takes these
     peaks = load_peaks(dev.device_kind) if require_tpu else {}
     enable_cache()
     counter = observe.CompileCounter()
@@ -360,21 +386,25 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     log(f"window: {len(off.due)} requests offered over {seconds}s, "
         f"{n_in_window} batches dispatched, {len(responses)} answered; "
         f"programs lowered in the window: {counter.n}")
-    stats = dev.memory_stats() or {}
+    mem_peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+                 for d in devs]
     run = Run(cell=cell, seconds=seconds, t0=t0, t1=t1, setup_s=setup_s,
               due=t0 + off.due, lag_s=lag, responses=responses,
               batches=tap.batches[:n_in_window],
               build={"index_s": system.index_seconds,
                      "hint_s": system.hint_seconds},
-              m=system.db.m, n=system.db.n, peaks=peaks)
+              m=system.db.m, n=system.db.n, peaks=peaks, chips=cell.chips,
+              shard_rows=system.server.db.shape[0] // cell.chips,
+              obs=loop.obs)
     if trace:
         run.trace = trace_reduce.reduce_dir(TRACE_DIR)
         if peaks:
             least, bound = work.least_seconds(
-                run.m, run.n, built.engine["max_batch"],
+                run.shard_rows, run.n, built.engine["max_batch"],
                 peaks["int8_ops_per_s"], peaks["hbm_bytes_per_s"])
-            log(f"answer roofline at b={built.engine['max_batch']}: "
-                f"{bound}-bound, least {least * 1e3:.3f} ms")
+            log(f"answer roofline at b={built.engine['max_batch']} over "
+                f"{run.shard_rows} rows a chip: {bound}-bound, least "
+                f"{least * 1e3:.3f} ms")
     checks = check.compare(cell, run, tap, system, built.corp, off.queries,
                            seed, on_tpu=dev.platform == "tpu" and error is None)
     if error is not None:
@@ -382,7 +412,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         raise ServedPathError({
             "correct": False, "attempted": len(off.due),
             "failed": checks["requests_unanswered"][0], "metrics": {},
-            "device": _device(dev),
+            "device": _device(devs, mem_peaks),
             "checks": {k: {"value": v, "limit": lim}
                        for k, (v, lim) in checks.items()}})
     metrics = {}
@@ -390,8 +420,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         v = read_metric(spec["name"], run)
         if v is not None:
             metrics[spec["name"]] = {"value": v, "unit": spec["unit"]}
-    device = _device(dev)
-    device["memory_peak_bytes"] = stats.get("peak_bytes_in_use")
+    device = _device(devs, mem_peaks)
     result = {
         "correct": all(v <= lim for v, lim in checks.values()),
         "attempted": len(off.due),

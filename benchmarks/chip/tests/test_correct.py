@@ -69,6 +69,20 @@ def test_sound_run_is_correct():
     assert list(res)[-1] == "checks"
 
 
+def test_run_carries_the_engines_obs(monkeypatch):
+    """A counter reader needs only ``run.obs``: the window's engine counts
+    one encrypt program per batch it dispatched."""
+    seen = []
+    read = run.read_metric
+    monkeypatch.setattr(run, "read_metric",
+                        lambda name, r: seen.append(r) or read(name, r))
+    assert _run()["correct"]
+    r = seen[0]
+    programs = r.obs.counter("serve.encrypt.programs").value
+    assert programs >= len(r.batches) > 0
+    assert r.chips == 1 and r.shard_rows == r.m
+
+
 def test_control_three_limbs_is_not_correct():
     res = _run(observe.control())
     assert not res["correct"]

@@ -7,10 +7,18 @@ the four 8-bit limbs of each query word: ``8·m·n·b`` integer operations
 least traffic is the database streamed once plus the queries read and the
 u32 products written.  Only the real query columns count: padding that an
 implementation adds is not work the answer needs.
+
+On a row-sharded server every chip answers its own row slice at once, so
+one chip's least time is that of an answer over the rows it holds
+(``m`` on one chip), against that chip's peaks.
 """
 from __future__ import annotations
 
 LIMBS = 4
+#: The answer program as the device trace names it: the Pallas kernel's
+#: own jit on one chip, and the jit of ``collectives.row_shard_gemm``'s
+#: ``shard_map`` (its per-shard function is ``local``) on a row-sharded DB.
+ANSWER_MODULES = ("jit_modmatmul_pallas", "jit_local")
 
 
 def answer_ops(m: int, n: int, b: int) -> int:
