@@ -576,11 +576,19 @@ class PirRagSystem:
         to the host — it waits for the device work queued ahead of it),
         ``serve.plan.encrypt`` (every LWE encrypt; the legacy path's are
         one device program, `PIRClient.query_batch`) and
-        ``serve.plan.dispatch`` (answer and decode enqueued); the legacy
-        path's `complete()` opens ``serve.complete.fetch`` around its one
-        device-to-host copy.  The legacy path also counts its encrypts on
-        the `Obs`: ``serve.encrypt.programs`` (one per batch) and
-        ``serve.encrypt.batched_queries`` (B·P per batch).
+        ``serve.plan.dispatch`` (answer and decode enqueued).  On the
+        legacy path the dispatch holds ``serve.plan.dispatch.answer`` (the
+        queries placed on the DB's chips and the answer program enqueued)
+        and ``serve.plan.dispatch.decode`` (the batched recover enqueued),
+        and `complete()` opens ``serve.complete.fetch`` around its one
+        device-to-host copy, then ``serve.complete.parse`` around every
+        passage parse and ``serve.complete.rerank`` around every rerank.
+        The legacy path also counts on the `Obs`, per batch:
+        ``serve.encrypt.programs`` (one), ``serve.encrypt.batched_queries``
+        (B·P), ``serve.answer.chips`` (the chips the DB's rows shard over),
+        ``serve.plan.query_bytes`` (the encrypted queries placed on those
+        chips, 4·n·B·P a chip) and ``serve.complete.fetch_bytes`` (the
+        decoded columns fetched, m·B·P).
         """
         span = obs.span if obs is not None else _no_span
         if key is None:
@@ -620,21 +628,31 @@ class PirRagSystem:
         # is pure host work (one ready-array fetch + parse + rerank) and
         # never queues behind other in-flight device chains
         with span("serve.plan.dispatch"):
-            ans = self.server.answer(qs)                     # (m, B·P)
-            cols = client.recover_batch(ans, secrets)
+            with span("serve.plan.dispatch.answer"):
+                ans = self.server.answer(qs)                 # (m, B·P)
+            with span("serve.plan.dispatch.decode"):
+                cols = client.recover_batch(ans, secrets)
+        if obs is not None:         # counts only: never an index
+            chips = self.server.n_shards
+            obs.counter("serve.answer.chips").inc(chips)
+            obs.counter("serve.plan.query_bytes").inc(qs.nbytes * chips)
 
         def complete():
             with span("serve.complete.fetch"):
                 cols_np = np.asarray(cols)
-            out = []
-            for b in range(len(query_embs)):
-                docs = []
-                for j in range(p):
-                    docs.extend(chunking.deserialize_docs(
-                        cols_np[:, b * p + j], emb_dim))
-                out.append(rerank.rerank(
-                    np.asarray(query_embs[b], np.float32), docs, top_ks[b]))
-            return out
+            if obs is not None:
+                obs.counter("serve.complete.fetch_bytes").inc(cols_np.nbytes)
+            # every parse, then every rerank: the two show as spans apart
+            with span("serve.complete.parse"):
+                docs = [[] for _ in range(n_req)]
+                for b in range(n_req):
+                    for j in range(p):
+                        docs[b].extend(chunking.deserialize_docs(
+                            cols_np[:, b * p + j], emb_dim))
+            with span("serve.complete.rerank"):
+                return [rerank.rerank(np.asarray(query_embs[b], np.float32),
+                                      docs[b], top_ks[b])
+                        for b in range(n_req)]
 
         return InflightBatch(_complete=complete, pending=(cols,))
 

@@ -405,8 +405,10 @@ def base_live():
 # -- the spans mirrored into the profiler's trace -----------------------------
 
 MIRRORED = ("serve.plan", "serve.plan.pick", "serve.plan.encrypt",
-            "serve.plan.dispatch", "serve.gemm", "serve.complete",
-            "serve.complete.fetch")
+            "serve.plan.dispatch", "serve.plan.dispatch.answer",
+            "serve.plan.dispatch.decode", "serve.gemm", "serve.complete",
+            "serve.complete.fetch", "serve.complete.parse",
+            "serve.complete.rerank")
 
 
 def _drive_profiled(base, corp, ops, log_dir=None):
@@ -458,15 +460,19 @@ def test_profiler_trace_holds_every_mirrored_span(profiled):
     assert set(MIRRORED) <= names
     assert "serve.tick" not in names           # the spin root stays out
     assert not any(n.startswith("bench.") for n in names)
-    # each plan holds exactly one pick, one encrypt and one dispatch
-    for name, t0, t1, _, line in events:
+    # each plan holds exactly one pick, one encrypt and one dispatch; a
+    # single-probe (legacy path) plan's dispatch one answer and one decode
+    for name, t0, t1, stats, line in events:
         if name == "serve.plan":
             inner = [e[0] for e in events if e[4] == line
                      and t0 <= e[1] and e[2] <= t1
                      and e[0].startswith("serve.plan.")]
-            assert sorted(inner) == ["serve.plan.dispatch",
-                                     "serve.plan.encrypt",
-                                     "serve.plan.pick"]
+            legacy = (["serve.plan.dispatch.answer",
+                       "serve.plan.dispatch.decode"]
+                      if stats["multi_probe"] == 1 else [])
+            assert sorted(inner) == sorted(["serve.plan.dispatch",
+                                            "serve.plan.encrypt",
+                                            "serve.plan.pick"] + legacy)
 
 
 def test_profiler_spans_join_by_batch_id(profiled):

@@ -1,10 +1,13 @@
 """PIR-RAG end-to-end: private retrieval returns the right documents."""
+import json
+
 import jax
 import numpy as np
 import pytest
 
 from repro.core import pipeline
 from repro.data import corpus as corpus_lib
+from _mesh_harness import run_sub
 
 
 @pytest.fixture(scope="module")
@@ -200,3 +203,67 @@ def test_balanced_build_reduces_downlink():
     q = corp.embeddings[0]
     top, _ = balanced.query(q, top_k=3, key=jax.random.PRNGKey(1))
     assert top and top[0][1] > 0.5
+
+
+SERVE_SPANS_BODY = """
+import json
+from repro.core import pipeline
+from repro.data import corpus as corpus_lib
+from repro.launch.mesh import make_chunk_mesh
+from repro.obs import Obs
+from repro.serve.engine import PipelinedServeLoop
+
+corp = corpus_lib.make_corpus(0, 300, emb_dim=32, n_topics=8)
+mesh = make_chunk_mesh({n}) if {n} > 1 else None
+system = pipeline.PirRagSystem.build(corp.texts, corp.embeddings,
+                                     n_clusters=8, kmeans_iters=15,
+                                     impl="xla", seed=1, mesh=mesh)
+loop = PipelinedServeLoop(system, max_batch=3, deadline_ms=0.0, depth=2,
+                          seed=0, obs=Obs(trace=True))
+for rid in range(7):
+    loop.submit(rid, corp.embeddings[rid * 40], top_k=4,
+                multi_probe=1 + rid % 2)
+loop.drain()
+spans = loop.obs.tracer.spans
+by_sid = {{s.sid: s for s in spans}}
+same = []
+for p in (1, 2):
+    embs, key = corp.embeddings[[5, 77, 150]], jax.random.PRNGKey(p)
+    with_obs = system.query_batch_async(embs, top_k=4, multi_probe=p,
+                                        key=key, obs=Obs()).complete()
+    same.append(with_obs == system.query_batch_async(
+        embs, top_k=4, multi_probe=p, key=key).complete())
+print(json.dumps({{
+    "n_shards": system.server.n_shards, "m": system.db.m, "n": system.db.n,
+    "columns": [s.attrs["batch"] * s.attrs["multi_probe"] for s in spans
+                if s.name == "serve.plan"],
+    "parents": [[s.name, by_sid[s.parent].name] for s in spans
+                if s.name.count(".") == 3
+                or s.name.startswith("serve.complete.")],
+    "counters": loop.obs.metrics_dict(), "same_topk": same}}))
+"""
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_dispatch_and_complete_stages_are_spans_and_counted(n_devices):
+    """On one device and on a four-device row-sharded mesh: the answer and
+    decode are spans inside the dispatch, the parse and rerank inside the
+    complete; per batch of C query columns the counters add the shard
+    count, 4·n·C bytes of queries a shard and m·C fetched bytes; an `Obs`
+    changes no top-k list."""
+    out = json.loads(run_sub(SERVE_SPANS_BODY.format(n=n_devices),
+                             n_devices=n_devices).strip().splitlines()[-1])
+    shards, cols = out["n_shards"], out["columns"]
+    assert shards == n_devices and len(cols) >= 3
+    assert sorted(map(tuple, out["parents"])) == sorted(
+        [("serve.plan.dispatch.answer", "serve.plan.dispatch"),
+         ("serve.plan.dispatch.decode", "serve.plan.dispatch"),
+         ("serve.complete.fetch", "serve.complete"),
+         ("serve.complete.parse", "serve.complete"),
+         ("serve.complete.rerank", "serve.complete")] * len(cols))
+    c = out["counters"]
+    assert c["serve.answer.chips"] == shards * len(cols)
+    assert c["serve.plan.query_bytes"] == 4 * out["n"] * sum(cols) * shards
+    assert c["serve.complete.fetch_bytes"] == out["m"] * sum(cols)
+    assert c["serve.encrypt.batched_queries"] == sum(cols)
+    assert out["same_topk"] == [True, True]
