@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import chunking, clustering, pir, rerank
+from repro.kernels import ops
 
 
 @dataclasses.dataclass
@@ -586,6 +587,8 @@ class PirRagSystem:
         The legacy path also counts on the `Obs`, per batch:
         ``serve.encrypt.programs`` (one), ``serve.encrypt.batched_queries``
         (B·P), ``serve.answer.chips`` (the chips the DB's rows shard over),
+        ``serve.answer.kernel_cols`` (the query columns the answer computes,
+        B·P after the kernel's padding: `kernels.ops.kernel_cols`),
         ``serve.plan.query_bytes`` (the encrypted queries placed on those
         chips, 4·n·B·P a chip) and ``serve.complete.fetch_bytes`` (the
         decoded columns fetched, m·B·P).
@@ -635,6 +638,8 @@ class PirRagSystem:
         if obs is not None:         # counts only: never an index
             chips = self.server.n_shards
             obs.counter("serve.answer.chips").inc(chips)
+            obs.counter("serve.answer.kernel_cols").inc(
+                ops.kernel_cols(orders.size, self.server.cfg.impl))
             obs.counter("serve.plan.query_bytes").inc(qs.nbytes * chips)
 
         def complete():

@@ -18,14 +18,54 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.modmatmul import modmatmul_pallas
+from repro.kernels.modmatmul import LANES, N_LIMBS, modmatmul_pallas
 
 U32 = jnp.uint32
 
-#: Default (bm, bn, bb) tile of the modmatmul kernel.  A DB of any row count
-#: whose column count is a multiple of bn is used in place (the kernel's
-#: last row tile may be partial); other widths cost a padded device copy.
-BLOCK = (256, 512, 128)
+#: Row tile of the modmatmul kernel.  A DB of any row count is used in
+#: place (the kernel's last row tile may be partial).
+BLOCK_ROWS = 512
+#: The contraction tile is the widest multiple of 512 up to this that
+#: divides the DB's width padded to 512: a DB whose width is a multiple of
+#: 512 is used in place, other widths cost a padded device copy.
+MAX_BLOCK_COLS = 4096
+_COL_QUANTUM = 512
+
+
+def pick_block(n: int, b: int) -> tuple[int, int, int]:
+    """The kernel's (bm, bn, bb) tile for an (m, n) DB and b query columns.
+
+    Picked from shapes alone.  Up to 32 query columns are one column tile
+    of exactly b (its 4·b limbs fit one 128-lane MXU operand), so a serving
+    batch pays for no padded columns; wider products, such as the hint
+    GEMM, take tiles of 128 columns.
+    """
+    n_pad = -(-n // _COL_QUANTUM) * _COL_QUANTUM
+    bn = max(c for c in range(_COL_QUANTUM, MAX_BLOCK_COLS + 1, _COL_QUANTUM)
+             if n_pad % c == 0)
+    return BLOCK_ROWS, bn, _col_tile(b)
+
+
+def _col_tile(b: int) -> int:
+    return b if N_LIMBS * b <= LANES else LANES
+
+
+def kernel_cols(b: int, impl: str = "auto") -> int:
+    """Query columns the answer computes for a b-column query, after padding.
+
+    The Pallas kernel computes whole column tiles (`pick_block`); the XLA
+    path computes b.
+    """
+    if _resolve(impl) != "pallas":
+        return b
+    bb = _col_tile(b)
+    return -(-b // bb) * bb
+
+
+def _resolve(impl: str) -> str:
+    if impl == "auto":
+        return "pallas" if jax.default_backend() == "tpu" else "xla"
+    return impl
 
 
 #: jit'd oracle: fuses the u8→u32 widening into the GEMM instead of
@@ -137,11 +177,12 @@ def add_delta(hint: jax.Array, delta: jax.Array) -> jax.Array:
 
 
 def modmatmul(db: jax.Array, q: jax.Array, *, impl: str = "auto",
-              block: tuple[int, int, int] = BLOCK) -> jax.Array:
+              block: tuple[int, int, int] | None = None) -> jax.Array:
     """Exact (db @ q) mod 2^32.
 
     db: (m, n) uint8 (entries < plaintext modulus p ≤ 256).
     q:  (n,) or (n, b) uint32.
+    block: the kernel's (bm, bn, bb) tile; by default `pick_block`'s.
     Returns uint32 of shape (m,) or (m, b).
     """
     if db.dtype != jnp.uint8:
@@ -151,14 +192,12 @@ def modmatmul(db: jax.Array, q: jax.Array, *, impl: str = "auto",
 
     was_vec = q.ndim == 1
     q2 = q[:, None] if was_vec else q
-
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
+    impl = _resolve(impl)
 
     if impl == "xla":
         out = _modmatmul_ref_jit(db, q2)
     elif impl == "pallas":
-        bm, bn, bb = block
+        bm, bn, bb = block or pick_block(*q2.shape)
         # Hot-loop reuse: the serving DB is the same array object across
         # ticks, so its column-padded device layout is cached instead of
         # re-padded per call.  Queries change every call — pad inline.
@@ -167,11 +206,9 @@ def modmatmul(db: jax.Array, q: jax.Array, *, impl: str = "auto",
         pad = lambda: _pad_to(db, 1, bn)  # noqa: E731
         dbp = (pad() if isinstance(db, jax.core.Tracer) else
                _db_pad_cache.get((id(db), db.shape, bn), (db,), pad))
-        qp = _pad_to(_pad_to(q2, 0, bn), 1, bb)
         interpret = jax.default_backend() != "tpu"
-        out = modmatmul_pallas(dbp, qp, bm=bm, bn=bn, bb=bb,
+        out = modmatmul_pallas(dbp, _pad_to(q2, 0, bn), bm=bm, bn=bn, bb=bb,
                                interpret=interpret)
-        out = out[:, :q2.shape[1]]
     else:
         raise ValueError(f"unknown impl {impl!r}")
 
@@ -179,7 +216,7 @@ def modmatmul(db: jax.Array, q: jax.Array, *, impl: str = "auto",
 
 
 def hint_gemm(db: jax.Array, a_mat: jax.Array, *, impl: str = "auto",
-              block: tuple[int, int, int] = BLOCK) -> jax.Array:
+              block: tuple[int, int, int] | None = None) -> jax.Array:
     """Offline hint H = D · A (mod 2^32); same kernel, many query columns."""
     return modmatmul(db, a_mat, impl=impl, block=block)
 
@@ -198,8 +235,7 @@ def delta_gemm(new_cols: jax.Array, old_cols: jax.Array, a_j: jax.Array, *,
 
     new_cols/old_cols: (m, J) uint8.  a_j: (J, k) uint32.
     """
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
+    impl = _resolve(impl)
     if impl == "xla":
         diff = new_cols.astype(U32) - old_cols.astype(U32)
         return ref.modmatmul_ref(diff, a_j)
@@ -263,7 +299,7 @@ def bucketed_modmatmul_sharded(stack: jax.Array, qs: jax.Array, mesh,
 
 def bucketed_modmatmul(dbs: Sequence[jax.Array], qs: jax.Array, *,
                        impl: str = "auto",
-                       block: tuple[int, int, int] = BLOCK
+                       block: tuple[int, int, int] | None = None
                        ) -> list[jax.Array]:
     """Per-bucket exact (D_b @ q_b) mod 2^32 — the batch-PIR server op.
 
@@ -306,8 +342,7 @@ def bucketed_modmatmul(dbs: Sequence[jax.Array], qs: jax.Array, *,
         if d.shape[1] != width:
             raise ValueError(f"bucket width {d.shape[1]} != query width {width}")
 
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
+    impl = _resolve(impl)
 
     if impl == "xla":
         # one (m_b, W) @ (W, C) call per bucket — C stacked client columns
@@ -315,7 +350,7 @@ def bucketed_modmatmul(dbs: Sequence[jax.Array], qs: jax.Array, *,
         # the old per-column matvec loop (parity-tested bitwise)
         out = [_matvec_u32(d, q3[b]) for b, d in enumerate(dbs)]
     elif impl == "pallas":
-        bm, bn, bb = block
+        bm, bn, bb = block or pick_block(width, q3.shape[2])
         m_pad = max(d.shape[0] for d in dbs)
         m_pad += (-m_pad) % bm
         restack = lambda: jnp.stack(  # noqa: E731
@@ -326,11 +361,11 @@ def bucketed_modmatmul(dbs: Sequence[jax.Array], qs: jax.Array, *,
                      (tuple(id(d) for d in dbs),
                       tuple(d.shape for d in dbs), bm, bn),
                      tuple(dbs), restack))
-        qp = _pad_to(_pad_to(q3, 1, bn), 2, bb)
         interpret = jax.default_backend() != "tpu"
         full = jax.vmap(lambda d, q: modmatmul_pallas(
-            d, q, bm=bm, bn=bn, bb=bb, interpret=interpret))(stack, qp)
-        out = [full[b, :d.shape[0], :q3.shape[2]] for b, d in enumerate(dbs)]
+            d, q, bm=bm, bn=bn, bb=bb, interpret=interpret))(
+                stack, _pad_to(q3, 1, bn))
+        out = [full[b, :d.shape[0]] for b, d in enumerate(dbs)]
     else:
         raise ValueError(f"unknown impl {impl!r}")
 
@@ -352,8 +387,7 @@ def kmeans_assign(x: jax.Array, c: jax.Array, *, impl: str = "auto",
     `row_shard_assign`), so the same fused kernel serves every layout —
     one call sees only its (rows_local/blocks, d) slice either way.
     """
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
+    impl = _resolve(impl)
     if impl == "xla":
         return ref.kmeans_assign_ref(x, c)
     from repro.kernels.kmeans_assign import kmeans_assign_pallas

@@ -10,7 +10,7 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.kernels import ops, ref
-from repro.kernels.modmatmul import modmatmul_pallas
+from repro.kernels.modmatmul import modmatmul_pallas, signed_digits
 
 
 def _rand_db_q(seed, m, n, b):
@@ -33,10 +33,18 @@ def test_kernel_exact_aligned(m, n, b):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+# 4608 = 9·512: the default contraction tile is 1536, three steps of it
+_N_STEPS = 4608
+
+
 @pytest.mark.parametrize("m,n,b", [
     (100, 300, 1),            # ragged everything + matvec
     (257, 513, 3),
     (31, 1025, 129),
+    # at the default tiles, over several contraction steps, every column
+    # width the tile choice can take: one tile of b (b ≤ 32), tiles of 128
+    # padded (33) or whole (128, and the hint GEMM's 1024)
+    *[(40, _N_STEPS, b) for b in (1, 2, 7, 16, 17, 33, 128, 1024)],
 ])
 def test_ops_wrapper_pads_ragged(m, n, b):
     db, q = _rand_db_q(1, m, n, b)
@@ -177,3 +185,48 @@ def test_scatter_and_add_helpers_exact():
     np.asarray(hint)                   # the HINT is never donated
     with pytest.raises(RuntimeError):
         np.asarray(delta)              # the transient delta is
+
+
+# --- the default tiles, picked from shapes (ops.pick_block) ----------------
+
+@pytest.mark.parametrize("word", [0xFFFFFFFF,   # digits (−1, 0, 0, 0)
+                                  0x7F7F7F80,   # every digit −128
+                                  0x7F7F7F7F])  # every digit +127
+@pytest.mark.parametrize("b", [7, 128])
+def test_extreme_values_at_default_tiles(word, b):
+    """All-255 DB × extreme query words: i32 wraparound across the
+    contraction and the once-only column correction."""
+    db = jnp.full((40, _N_STEPS), 255, jnp.uint8)
+    q = jnp.full((_N_STEPS, b), word, jnp.uint32)
+    got = ops.modmatmul(db, q, impl="pallas")
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(ref.modmatmul_ref(db, q)))
+
+
+def test_signed_digits_recompose_each_word():
+    words = np.array([0, 1, 127, 128, 255, 256, 0x7F7F7F7F, 0x7F7F7F80,
+                      0x80808080, 0xFFFFFFFF], np.uint32)
+    words = np.concatenate([words, np.random.default_rng(6).integers(
+        0, 2**32, 1000, dtype=np.uint32)])[:, None]
+    digits = np.asarray(signed_digits(jnp.asarray(words))).astype(np.int64)
+    assert digits.shape == (len(words), 4, 1)
+    assert digits.min() >= -128 and digits.max() <= 127
+    back = sum(digits[:, l] << (8 * l) for l in range(4)) % (1 << 32)
+    np.testing.assert_array_equal(back, words)
+
+
+@pytest.mark.parametrize("n,b,block", [
+    (4096, 1, (512, 4096, 1)),        # a serving batch: one tile of b
+    (4096, 16, (512, 4096, 16)),
+    (4096, 32, (512, 4096, 32)),
+    (4096, 33, (512, 4096, 128)),     # past 32: tiles of 128
+    (4096, 1024, (512, 4096, 128)),   # the hint GEMM keeps the wide tile
+    (300, 8, (512, 512, 8)),          # a narrow DB pads to 512 columns
+    (1024, 8, (512, 1024, 8)),        # a bucket's width is used in place
+    (_N_STEPS, 7, (512, 1536, 7)),    # three contraction steps
+])
+def test_tile_is_picked_from_shapes(n, b, block):
+    assert ops.pick_block(n, b) == block
+    cols = ops.kernel_cols(b, "pallas")
+    assert cols == (b if b <= 32 else -(-b // 128) * 128)
+    assert ops.kernel_cols(b, "xla") == b

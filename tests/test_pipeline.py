@@ -142,6 +142,30 @@ def test_batched_encrypt_counts_and_reuses_a(system_and_corpus, monkeypatch):
     assert a_seen[0] is a_seen[1] is sys.server.a_matrix
 
 
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_answer_counts_the_kernels_padded_columns(system_and_corpus, impl):
+    """Per batch of C query columns `serve.answer.kernel_cols` adds the
+    columns the answer computes: C through XLA; through the Pallas kernel
+    (interpreted here) C up to 32, else whole tiles of 128.  Either way the
+    decoded columns are the XLA system's."""
+    from repro.kernels import ops
+    from repro.obs import Obs
+    ref_sys, corp = system_and_corpus
+    sys = pipeline.PirRagSystem.build(corp.texts, corp.embeddings,
+                                      n_clusters=8, kmeans_iters=15,
+                                      impl=impl, seed=1)
+    obs = Obs()
+    for b in (3, 40):
+        embs, key = corp.embeddings[:b], jax.random.PRNGKey(b)
+        infl = sys.query_batch_async(embs, top_k=2, key=key, obs=obs)
+        want = ref_sys.query_batch_async(embs, top_k=2, key=key)
+        np.testing.assert_array_equal(np.asarray(infl.pending[0]),
+                                      np.asarray(want.pending[0]))
+    padded = {"xla": 3 + 40, "pallas": 3 + 128}[impl]
+    assert ops.kernel_cols(3, impl) + ops.kernel_cols(40, impl) == padded
+    assert obs.metrics_dict()["serve.answer.kernel_cols"] == padded
+
+
 def test_build_seed_streams_are_independent():
     """One build seed, TWO independent fold_in streams.
 
@@ -263,6 +287,7 @@ def test_dispatch_and_complete_stages_are_spans_and_counted(n_devices):
          ("serve.complete.rerank", "serve.complete")] * len(cols))
     c = out["counters"]
     assert c["serve.answer.chips"] == shards * len(cols)
+    assert c["serve.answer.kernel_cols"] == sum(cols)
     assert c["serve.plan.query_bytes"] == 4 * out["n"] * sum(cols) * shards
     assert c["serve.complete.fetch_bytes"] == out["m"] * sum(cols)
     assert c["serve.encrypt.batched_queries"] == sum(cols)

@@ -62,6 +62,12 @@ def _compile(fn, *args) -> str:
     (65536, 4096, 128),       # online answer, one query tile
     (65536, 4096, 1024),      # offline hint GEMM, H = D·A at LWE k = 1024
     (65536 + 192, 4096, 128),  # a row count ending in a partial row tile
+    # msmarco.steady's DB: serving batches of 1, 8 and 16 (one narrow column
+    # tile each), then its hint GEMM at LWE k = 1024
+    (1_559_040, 4096, 1),
+    (1_559_040, 4096, 8),
+    (1_559_040, 4096, 16),
+    (1_559_040, 4096, 1024),
 ])
 def test_modmatmul_compiles_to_kernel(one_chip, on_tpu, m, n, b):
     text = _compile(ops.modmatmul, _sds((m, n), jnp.uint8, one_chip),
@@ -107,15 +113,29 @@ def test_client_batched_encrypt_compiles(one_chip, c):
     assert "ENTRY" in text
 
 
+def _row_shard_answer(topo, rows: int, b: int) -> str:
+    """The compiled row-sharded answer over four chips of ``rows`` each."""
+    mesh = make_chunk_mesh(4, topo.devices)
+    fn = collectives.row_shard_gemm(mesh, ("chunks",), q_switch=1 << 16)
+    return fn.lower(
+        _sds((4 * rows, 4096), jnp.uint8,
+             NamedSharding(mesh, P("chunks", None))),
+        _sds((4096, b), jnp.uint32, NamedSharding(mesh, P()))
+    ).compile().as_text()
+
+
 def test_row_shard_gemm_compiles_without_collectives(topo, on_tpu):
     """Each chip's row slice ends in a partial kernel row tile, as a packed
     DB's m/4 usually does: the kernel takes it in place."""
-    mesh = make_chunk_mesh(4, topo.devices)
-    fn = collectives.row_shard_gemm(mesh, ("chunks",), q_switch=1 << 16)
-    text = fn.lower(
-        _sds((4 * (65536 + 192), 4096), jnp.uint8,
-             NamedSharding(mesh, P("chunks", None))),
-        _sds((4096, 128), jnp.uint32, NamedSharding(mesh, P()))
-    ).compile().as_text()
+    text = _row_shard_answer(topo, 65536 + 192, 128)
+    assert "tpu_custom_call" in text
+    assert not [c for c in COLLECTIVES if c in text]
+
+
+@pytest.mark.parametrize("b", [1, 16])
+def test_row_shard_gemm_at_the_sharded_cells_rows(topo, on_tpu, b):
+    """dpr-wiki.sharded4's answer: 266,304 DB rows a chip, a serving batch
+    of b queries copied to every chip; the kernel, and no collective."""
+    text = _row_shard_answer(topo, 266_304, b)
     assert "tpu_custom_call" in text
     assert not [c for c in COLLECTIVES if c in text]
