@@ -198,7 +198,7 @@ class PirRagSystem:
         return cls(centroids=cents, db=db, cfg=cfg, server=server, hint=hint,
                    setup_seconds=t_end - t0, index_seconds=t_index - t0,
                    hint_seconds=t_end - t_index, assignment=assign,
-                   mesh=mesh, mesh_axes=server.mesh_axes,
+                   mesh=mesh, mesh_axes=axes,
                    _qkey=_fresh_client_key())
 
     @classmethod
@@ -256,7 +256,7 @@ class PirRagSystem:
         sys = cls(centroids=cents, db=db, cfg=cfg, server=server, hint=hint,
                   setup_seconds=t_hint - t0, index_seconds=t_index - t0,
                   hint_seconds=t_hint - t_index, assignment=assign,
-                  keyed=layout, mesh=mesh, mesh_axes=server.mesh_axes,
+                  keyed=layout, mesh=mesh, mesh_axes=axes,
                   _qkey=_fresh_client_key())
         sys.enable_batch(kappa=kappa, n_buckets=n_buckets, seed=batch_seed)
         sys.setup_seconds += sys.batch.setup_seconds

@@ -8,33 +8,36 @@ Protocol roles (honest-but-curious server):
             server:  ans = D·qu (mod 2^32)               — ONE modular GEMV
             client:  decode(ans − H·s) → column i of D   — the whole cluster
 
-The answer step is the system hot loop; it dispatches to the Pallas MXU
-kernel on TPU (`kernels/ops.modmatmul`).  Batched serving stacks queries from
+The answer step is the system hot loop; it runs the Pallas MXU kernel on
+TPU (`kernels/ops.modmatmul`).  Batched serving stacks queries from
 many clients into the column dimension, turning the GEMV into a GEMM.
 
 Beyond-paper: modulus-switched responses (q → 2^16) halve the downlink at a
 rounding-noise cost accounted in `lwe.noise_budget_ok`.
 
-Sharded serving (beyond-paper, `distributed.sharding.pir_rules`): pass
-``mesh=`` to row-shard the packed DB over the device mesh.  Queries
-replicate; every shard computes its own hint rows H_s = D_s·A and answer
-slice ans_s = D_s·qu with ZERO collectives (the contraction dim — the
-cluster axis — is never split), and the client decodes the concatenation.
-All sharded arithmetic is the same exact mod-2^32 kernel path, so results
-are bit-identical to the single-device layout (property-tested under the
+One server path (beyond-paper, `distributed.sharding.pir_rules`): the
+packed DB always row-shards over a device mesh — the caller's ``mesh=``, or
+a one-device mesh on a single chip.  Queries replicate; every shard
+computes its own hint rows H_s = D_s·A and answer slice ans_s = D_s·qu with
+ZERO collectives (the contraction dim — the cluster axis — is never split),
+and the client decodes the concatenation.  The hint, the answer (modulus
+switch included) and a commit's delta are each one jitted `shard_map`
+from `distributed.collectives` on every layout, so results are
+bit-identical across shard counts (property-tested under the
 8-fake-device harness in tests/test_sharded_pir.py).
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
-import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
-from repro.core import lwe
+from repro.core import clustering, lwe
+from repro.distributed import collectives
 from repro.kernels import ops
 
 U32 = jnp.uint32
@@ -75,21 +78,33 @@ class PIRConfig:
 # Server
 # ---------------------------------------------------------------------------
 
+def _one_device_mesh(db) -> Mesh:
+    """The ``("chunks",)`` mesh of one device a server without ``mesh=``
+    serves on: the DB's device when it has one, else the default device."""
+    devices = db.devices() if isinstance(db, jax.Array) else ()
+    device = next(iter(devices)) if len(devices) == 1 else jax.devices()[0]
+    return Mesh(np.array([device]), ("chunks",),
+                axis_types=(AxisType.Auto,))
+
+
 class PIRServer:
     """Holds the plaintext DB (u8, entries < p) and answers encrypted queries.
 
-    With ``mesh=`` the DB row-shards over the mesh (the ``chunks`` logical
-    axis of `sharding.pir_rules`); rows are zero-padded up to a multiple of
-    the shard count so `shard_map` sees equal slices (a DB packed in the
-    default 256-byte chunks needs none).  The padding rows are all-zero
-    on both the DB and the hint, so answers/decodes are unaffected — every
-    public method still speaks global (m, ...) shapes.  Each sharded call
-    is one compiled program, the cut back to m rows included, so what it
-    moves between devices shows in that program's HLO.
+    The DB row-shards over a mesh (the ``chunks`` logical axis of
+    `sharding.pir_rules`): ``mesh=``, or without one (``self.mesh`` stays
+    ``None``) a one-device ``("chunks",)`` mesh over the DB's device, so one
+    code path serves every layout; ``mesh_axes`` and ``n_shards`` are the
+    mesh's.  Rows are zero-padded up to a multiple of the shard count so
+    `shard_map` sees equal slices (a DB packed in the default 256-byte
+    chunks needs none).  The padding rows are all-zero on both the DB and
+    the hint, so answers/decodes are unaffected — every public method still
+    speaks global (m, ...) shapes.  The hint, the answer and a commit's
+    delta are each one compiled program, the cut back to m rows included,
+    so what it moves between devices shows in that program's HLO.
 
     ``db`` accepts three layouts (all (m, n) uint8 semantics):
 
-      * a jax array — committed/resharded as before;
+      * a jax array — placed on the row sharding (on one device, no copy);
       * a host numpy array — padded host-side and transferred straight into
         the sharded layout (no device-0 commit);
       * a list/tuple of S per-shard host row slices ((m_pad/S, n) each,
@@ -104,46 +119,40 @@ class PIRServer:
                  mesh=None, mesh_axes: tuple[str, ...] | None = None):
         self.cfg = cfg
         self.mesh = mesh
-        self.mesh_axes: tuple[str, ...] | None = None
-        self._row_pad = 0
-        if mesh is not None:
-            from repro.core import clustering
-            axes, shards = clustering.resolve_mesh_axes(mesh, mesh_axes)
-            self.mesh_axes = axes
-            self.n_shards = shards
-            self._row_pad = (-cfg.m) % shards
-            self._db_sharding = NamedSharding(mesh,
-                                              PartitionSpec(axes, None))
-            self._replicated = NamedSharding(mesh, PartitionSpec())
-            if isinstance(db, (list, tuple)):
-                db = self._assemble_row_shards(db)
-            elif isinstance(db, np.ndarray):
-                assert db.shape == (cfg.m, cfg.n), (db.shape, (cfg.m, cfg.n))
-                assert db.dtype == np.uint8
-                if self._row_pad:
-                    padded = np.zeros((cfg.m + self._row_pad, cfg.n),
-                                      np.uint8)
-                    padded[:cfg.m] = db
-                    db = padded
-                db = jax.device_put(db, self._db_sharding)
-            else:
-                assert db.shape == (cfg.m, cfg.n), (db.shape, (cfg.m, cfg.n))
-                assert db.dtype == jnp.uint8
-                if self._row_pad:
-                    db = jnp.pad(jnp.asarray(db),
-                                 ((0, self._row_pad), (0, 0)))
-                db = jax.device_put(db, self._db_sharding)
+        if mesh is None:
+            mesh, mesh_axes = _one_device_mesh(db), None
+        self._mesh = mesh
+        self.mesh_axes, self.n_shards = clustering.resolve_mesh_axes(
+            mesh, mesh_axes)
+        self._row_pad = (-cfg.m) % self.n_shards
+        self._db_sharding = NamedSharding(mesh,
+                                          PartitionSpec(self.mesh_axes, None))
+        self._replicated = NamedSharding(mesh, PartitionSpec())
+        if isinstance(db, (list, tuple)):
+            db = self._assemble_row_shards(db)
         else:
-            self.n_shards = 1
-            if isinstance(db, np.ndarray):
-                db = jnp.asarray(db)
             assert db.shape == (cfg.m, cfg.n), (db.shape, (cfg.m, cfg.n))
-            assert db.dtype == jnp.uint8
+            assert db.dtype == np.uint8
+            if self._row_pad:
+                xp = np if isinstance(db, np.ndarray) else jnp
+                db = xp.pad(db, ((0, self._row_pad), (0, 0)))
+            db = jax.device_put(db, self._db_sharding)
         self.db = db
         self._a_mat: jax.Array | None = None   # lazy; immutable per config
-        self._answer_fn = None                 # cached shard_map'd hot path
-        self._hint_fn = None
-        self._delta_fn = None
+        axes, impl = self.mesh_axes, cfg.impl
+        self._hint_fn = self._cut_to_m(
+            collectives.row_shard_gemm(mesh, axes, impl=impl))
+        self._answer_fn = self._cut_to_m(collectives.row_shard_gemm(
+            mesh, axes, impl=impl, q_switch=cfg.params.q_switch))
+        self._delta_fn = self._cut_to_m(
+            collectives.row_shard_delta_gemm(mesh, axes, impl=impl))
+
+    def __deepcopy__(self, memo):
+        """A copy owns its arrays and shares the mesh, its shardings and
+        programs, which hold devices (they do not copy)."""
+        new = copy.copy(self)
+        new.db, new._a_mat = copy.deepcopy((self.db, self._a_mat), memo)
+        return new
 
     def _assemble_row_shards(self, shards) -> jax.Array:
         """Place per-shard host row slices device-by-device and assemble.
@@ -181,38 +190,23 @@ class PIRServer:
     def setup(self) -> jax.Array:
         """Offline hint H = D·A ∈ Z_q^{m×k} (the heavy one-time GEMM).
 
-        Sharded servers compute per-shard hint rows H_s = D_s·A in place
-        (zero collectives) and return the global (m, k) view; the client
-        downloads it once, exactly like the single-device hint.
+        Each shard computes its own hint rows H_s = D_s·A in place (zero
+        collectives); the result is the global (m, k) view the client
+        downloads once.
         """
-        if self.mesh is None:
-            return ops.hint_gemm(self.db, self.a_matrix, impl=self.cfg.impl)
-        if self._hint_fn is None:
-            from repro.distributed import collectives
-            self._hint_fn = self._cut_to_m(collectives.row_shard_gemm(
-                self.mesh, self.mesh_axes, impl=self.cfg.impl))
-        a_rep = jax.device_put(self.a_matrix, self._replicated)
-        return self._hint_fn(self.db, a_rep)
+        return self._hint_fn(ops.kernel_db(self.db, self.cfg.impl),
+                             jax.device_put(self.a_matrix, self._replicated))
 
     def answer(self, qu: jax.Array) -> jax.Array:
         """Online answer: D·qu mod 2^32.  qu: (n,) or (n, batch) uint32.
 
-        Sharded servers replicate qu and run the shard_map'd row GEMM —
-        each device answers its own row slice, no collectives.
+        qu replicates and the shard_map'd row GEMM runs, the modulus
+        switch included — each device answers its own row slice, no
+        collectives.
         """
-        if self.mesh is None:
-            ans = ops.modmatmul(self.db, qu, impl=self.cfg.impl)
-            if self.cfg.params.q_switch is not None:
-                ans = lwe.switch_modulus(ans, self.cfg.params.q_switch)
-            return ans
-        if self._answer_fn is None:
-            from repro.distributed import collectives
-            self._answer_fn = self._cut_to_m(collectives.row_shard_gemm(
-                self.mesh, self.mesh_axes, impl=self.cfg.impl,
-                q_switch=self.cfg.params.q_switch))
         was_vec = qu.ndim == 1
         q2 = qu[:, None] if was_vec else qu
-        ans = self._answer_fn(self.db,
+        ans = self._answer_fn(ops.kernel_db(self.db, self.cfg.impl),
                               jax.device_put(q2, self._replicated))
         return ans[:, 0] if was_vec else ans
 
@@ -264,17 +258,10 @@ class PIRServer:
             old_g = jnp.concatenate([old_cols, unchanged], axis=1)
         else:
             cols_g, new_g, old_g = cols, new_cols, old_cols
-        a_j = self.a_matrix[cols_g]                        # (J', k)
-        if self.mesh is None:
-            return ops.delta_gemm(new_g, old_g, a_j, impl=self.cfg.impl)
-        if self._delta_fn is None:
-            from repro.distributed import collectives
-            self._delta_fn = self._cut_to_m(collectives.row_shard_delta_gemm(
-                self.mesh, self.mesh_axes, impl=self.cfg.impl))
         return self._delta_fn(
             jax.device_put(new_g, self._db_sharding),
             jax.device_put(old_g, self._db_sharding),
-            jax.device_put(a_j, self._replicated))
+            jax.device_put(self.a_matrix[cols_g], self._replicated))
 
     def stage_scatter(self, cols: jax.Array, new_cols: jax.Array, *,
                       donate: bool = False) -> jax.Array:
@@ -287,17 +274,10 @@ class PIRServer:
         alive at the runtime level.
         """
         cols, new_cols = self._pad_new_cols(cols, new_cols)
-        if self.mesh is None:
-            return ops.scatter_columns(self.db, cols, new_cols,
-                                       donate=donate)
-        if donate:
-            from repro.distributed import collectives
-            scatter = collectives.row_shard_scatter(
-                self.mesh, self.mesh_axes, donate=True)
-            return scatter(self.db, cols,
-                           jax.device_put(new_cols, self._db_sharding))
-        return jax.device_put(self.db.at[:, cols].set(new_cols),
-                              self._db_sharding)
+        scatter = collectives.row_shard_scatter(self._mesh, self.mesh_axes,
+                                                donate=donate)
+        return scatter(self.db, jax.device_put(cols, self._replicated),
+                       jax.device_put(new_cols, self._db_sharding))
 
     def stage_update(self, cols: jax.Array, new_cols: jax.Array, *,
                      donate: bool = False
@@ -324,7 +304,7 @@ class PIRServer:
 
             ΔH = ΔD[:,J] · A[J,:]  =  D_new[:,J]·A[J,:] − D_old[:,J]·A[J,:]
 
-        Both products go through the same `ops.modmatmul` kernel path as the
+        Both products go through the same `ops.modmatmul` kernel as the
         offline hint, so `H + ΔH` is bit-identical to `setup()` on the
         updated DB (all arithmetic exact mod 2^32).
 
@@ -336,10 +316,9 @@ class PIRServer:
         cancel exactly in ΔH while streamed mutation batches of varying size
         reuse a handful of compiled shapes instead of recompiling per batch.
 
-        Sharded servers scatter the new columns into the row-sharded DB and
-        run the delta GEMM shard_map'd: each shard patches only the hint
-        rows it owns, so the live-index commit is collective-free like the
-        answer path.
+        The new columns scatter into the row-sharded DB and the delta GEMM
+        runs shard_map'd: each shard patches only the hint rows it owns, so
+        the live-index commit is collective-free like the answer path.
 
         ``donate=True`` patches the DB buffer in place (see `stage_update`);
         callers must not retain the pre-update ``self.db`` array.
@@ -402,23 +381,18 @@ class PIRClient:
 
     def recover(self, ans: jax.Array, state: ClientQueryState) -> jax.Array:
         """Decode the server answer into the plaintext column (m,) u8."""
-        p = self.cfg.params
-        if p.q_switch is not None:
-            vals = lwe.decode_switched(ans, self.hint, state.secret, p)
-        else:
-            rec = lwe.hint_strip(ans, self.hint, state.secret)
-            vals = lwe.decode(rec, p)
-        return vals.astype(jnp.uint8)
+        return self.recover_batch(ans[:, None], state.secret[:, None])[:, 0]
 
     def recover_batch(self, ans: jax.Array, secrets: jax.Array) -> jax.Array:
         """Decode C answers at once: ans (m, C), secrets (k, C) → (m, C) u8.
 
         Every LWE decode op is exact integer arithmetic and shape
         polymorphic (the hint strip is one (m,k)·(k,C) matmul), so column
-        j here is BIT-IDENTICAL to ``recover(ans[:, j], state_j)`` — the
-        batched form exists so the serving pipeline can enqueue recovery
-        on the device stream at dispatch time instead of paying C
-        dispatch round-trips at the complete stage.
+        j here is BIT-IDENTICAL to ``recover(ans[:, j], state_j)``, which
+        is this call on one column — the batched form exists so the
+        serving pipeline can enqueue recovery on the device stream at
+        dispatch time instead of paying C dispatch round-trips at the
+        complete stage.
         """
         p = self.cfg.params
         if p.q_switch is not None:

@@ -10,8 +10,9 @@ we want the communication pattern pinned down rather than inferred:
     sequence; per-shard online-softmax partials combined with pmax/psum.
   * ``ring_psum`` — reduce via collective_permute ring, used by the gradient
     compression path so the wire format stays int8 end-to-end.
-  * ``row_shard_gemm`` / ``row_shard_delta_gemm`` — the PIR serving strategy
-    (`sharding.pir_rules`): the packed database row-shards over the mesh,
+  * ``row_shard_gemm`` / ``row_shard_delta_gemm`` / ``row_shard_scatter``
+    — the PIR server's one path (`sharding.pir_rules`): the packed database
+    row-shards over the mesh, a single chip being a one-device mesh,
     queries replicate, every shard answers its own row slice.  ZERO
     collectives on the hot path — the modular GEMM's contraction dim (the
     cluster axis) is never split, so per-shard answers are already final.
@@ -93,10 +94,13 @@ def row_shard_gemm(mesh: Mesh, axes: tuple[str, ...], *, impl: str = "auto",
                    q_switch: int | None = None):
     """Returns ans(db, q): the row-sharded modular GEMM  D·q  (mod 2^32).
 
-    db: (m, n) uint8 sharded P(axes, None) — each device holds a row slice
-    D_s.  q: (n, b) uint32 replicated.  Returns (m, b) sharded P(axes, None)
+    db: (m, n') uint8 sharded P(axes, None) — each device holds a row slice
+    D_s; n' is n or `ops.kernel_db`'s padded width, to which q's rows pad.
+    q: (n, b) uint32 replicated.  Returns (m, b) sharded P(axes, None)
     (uint16 when ``q_switch`` ≤ 2^16 — the modulus switch runs shard-local
-    too, so the downlink leaves each shard already compressed).
+    too, so the downlink leaves each shard already compressed).  One
+    program, ``jit_local`` in a device trace, on every layout `PIRServer`
+    serves: a single chip is a one-device mesh.
 
     Row sharding never splits the contraction dim, so each shard's answer
     slice  ans_s = D_s·q  is final: no psum, no all-gather — the compiled
@@ -110,6 +114,8 @@ def row_shard_gemm(mesh: Mesh, axes: tuple[str, ...], *, impl: str = "auto",
     from repro.kernels import ops
 
     def local(db_shard, q):
+        if q.shape[0] != db_shard.shape[1]:
+            q = jnp.pad(q, ((0, db_shard.shape[1] - q.shape[0]), (0, 0)))
         ans = ops.modmatmul(db_shard, q, impl=impl)
         if q_switch is not None:
             ans = lwe.switch_modulus(ans, q_switch)

@@ -22,8 +22,9 @@ So a contraction step does only the DB tile's work: load it, flip its top
 bits, one int8 MXU dot into an i32 accumulator.  Everything that does not
 depend on the DB tile happens once: the digit split and the column
 correction once per call, in the same jitted program as the ``pallas_call``
-(the device trace names the whole answer ``jit_modmatmul_pallas``), and the
-limb recombination once per output tile, after the last contraction step.
+(the server's answer is one program, ``jit_local`` in the device trace, on
+every layout: `distributed.collectives.row_shard_gemm`), and the limb
+recombination once per output tile, after the last contraction step.
 
 Blocking: D streams HBM→VMEM in (bm, bn) u8 tiles; the digit operand is
 VMEM-resident per (j, k) block; the accumulator is a VMEM scratch.  A column

@@ -86,19 +86,17 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
 class _LayoutCache:
     """Identity-keyed memo for device-resident kernel layouts.
 
-    The Pallas path pads (and thereby copies) a database operand whose width
-    is not whole column tiles on every call; in the serving hot loop the
-    database is the SAME array
-    object tick after tick, so the padded layout is cached and reused until a
-    commit swaps the array.  Keys carry ``id()`` plus shape/block, and every
-    entry pins the source array(s) so an entry can only be returned while its
-    key identity still refers to the array it was built from (a recycled
-    ``id()`` after GC can never alias: the pinned source keeps the id alive).
-    Bounded FIFO so retired epochs' layouts fall out on their own.  The
-    capacity stays small because only the live epoch's layout (plus, on the
-    serving path, at most one in-flight predecessor and the transient
-    delta-GEMM operands of a commit) can ever hit again — anything older is
-    a full-size padded copy pinning dead memory.
+    A layout (a DB's column-padded copy, a bucket stack) is built outside
+    any program, once per source array: the serving DB is the SAME array
+    object tick after tick, so its layout is reused until a commit swaps
+    the array.  Keys carry ``id()`` plus shape/block, and every entry pins
+    the source array(s) so an entry can only be returned while its key
+    identity still refers to the array it was built from (a recycled
+    ``id()`` after GC can never alias: the pinned source keeps the id
+    alive).  Bounded FIFO so retired epochs' layouts fall out on their own.
+    The capacity stays small because only the live epoch's layout (plus, on
+    the serving path, at most one in-flight predecessor) can ever hit
+    again — anything older is a full-size copy pinning dead memory.
     """
 
     def __init__(self, capacity: int = 4):
@@ -129,6 +127,20 @@ class _LayoutCache:
 
 _db_pad_cache = _LayoutCache()
 _bucket_stack_cache = _LayoutCache()
+
+
+def kernel_db(db: jax.Array, impl: str = "auto") -> jax.Array:
+    """``db`` as the modmatmul kernel reads it, built outside any program.
+
+    The Pallas kernel contracts whole 512-column quanta: another width is
+    zero-padded once per DB array (`_db_pad_cache`), not inside every call
+    of a program, where the pad copies the DB.  A caller zero-pads its
+    query rows to the returned width.  The XLA path reads ``db`` as it is.
+    """
+    if _resolve(impl) != "pallas" or db.shape[1] % _COL_QUANTUM == 0:
+        return db
+    return _db_pad_cache.get((id(db), db.shape), (db,),
+                             lambda: _pad_to(db, 1, _COL_QUANTUM))
 
 
 # ---------------------------------------------------------------------------
@@ -198,14 +210,10 @@ def modmatmul(db: jax.Array, q: jax.Array, *, impl: str = "auto",
         out = _modmatmul_ref_jit(db, q2)
     elif impl == "pallas":
         bm, bn, bb = block or pick_block(*q2.shape)
-        # Hot-loop reuse: the serving DB is the same array object across
-        # ticks, so its column-padded device layout is cached instead of
-        # re-padded per call.  Queries change every call — pad inline.
-        # Under a trace (shard_map, jit) the operand is a tracer: nothing to
-        # reuse, and a tracer must not outlive its trace in the cache.
-        pad = lambda: _pad_to(db, 1, bn)  # noqa: E731
-        dbp = (pad() if isinstance(db, jax.core.Tracer) else
-               _db_pad_cache.get((id(db), db.shape, bn), (db,), pad))
+        # A tracer must not outlive its trace in `kernel_db`'s cache: a
+        # jitted caller passes `kernel_db`'s array in.
+        dbp = (_pad_to(db, 1, bn) if block or isinstance(db, jax.core.Tracer)
+               else kernel_db(db, impl))
         interpret = jax.default_backend() != "tpu"
         out = modmatmul_pallas(dbp, _pad_to(q2, 0, bn), bm=bm, bn=bn, bb=bb,
                                interpret=interpret)

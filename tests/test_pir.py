@@ -6,6 +6,7 @@ import pytest
 from _mesh_harness import run_sub
 
 from repro.core import lwe, pir
+from repro.kernels import ops, ref
 
 
 def _setup(m=192, n=512, q_switch=1 << 16, seed=0, impl="xla"):
@@ -156,3 +157,76 @@ for name, (fn, *args) in progs.items():
     assert not moved or m % 4, (name, moved)
 print("OK")
 """, n_devices=4)
+
+
+COLLECTIVES = ("all-reduce", "all-gather", "all-to-all", "collective-permute",
+               "reduce-scatter")
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("q_switch", [None, 1 << 16])
+@pytest.mark.parametrize("b", [1, 7, 16, 40])
+def test_one_device_answer_is_the_row_shard_program(b, q_switch, impl):
+    """Without ``mesh=`` the server answers through its one row-shard
+    program over a one-device mesh: bit-identical to the oracle GEMM and
+    the modulus switch, one program named jit_local, no collective."""
+    m, n = 64, 512
+    db = jnp.asarray(np.random.default_rng(b).integers(0, 256, (m, n),
+                                                       dtype=np.uint8))
+    cfg = pir.make_config(m, n, impl=impl, q_switch=q_switch)
+    server = pir.PIRServer(cfg, db)
+    assert server.mesh is None and server.n_shards == 1
+    assert server.db.shape == (m, n)
+    calls = []
+    program = server._answer_fn
+    server._answer_fn = lambda *a: calls.append(a) or program(*a)
+    q = jnp.asarray(np.random.default_rng(100 + b).integers(
+        0, 2**32, (n, b), dtype=np.uint32))
+    got = server.answer(q)
+    want = ref.modmatmul_ref(db, q)
+    if q_switch is not None:
+        want = lwe.switch_modulus(want, q_switch)
+    assert len(calls) == 1
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    text = program.lower(*calls[0]).compile().as_text()
+    assert text.startswith("HloModule jit_local"), text.splitlines()[0]
+    assert not [c for c in COLLECTIVES if c in text]
+
+
+def test_one_device_server_pads_the_db_once_per_array():
+    """A width the kernel cannot take in place (300 → 512) is padded once
+    per DB array, outside the programs: two answers and the hint share one
+    padded copy, a commit's new array misses once, and the DB never enters
+    the answer program at its own width."""
+    m, n = 64, 300
+    rng = np.random.default_rng(3)
+    db = rng.integers(0, 256, (m, n), dtype=np.uint8)
+    cfg = pir.make_config(m, n, impl="pallas")
+    server = pir.PIRServer(cfg, jnp.asarray(db))
+    cache = ops._db_pad_cache
+    cache.clear()
+    q = jnp.asarray(rng.integers(0, 2**32, (n, 3), dtype=np.uint32))
+    a1, a2 = server.answer(q), server.answer(q)
+    hint = server.setup()
+    assert (cache.misses, cache.hits) == (1, 2)
+    want = lwe.switch_modulus(ref.modmatmul_ref(jnp.asarray(db), q),
+                              cfg.params.q_switch)
+    np.testing.assert_array_equal(np.asarray(a1), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(a2), np.asarray(want))
+    np.testing.assert_array_equal(
+        np.asarray(hint),
+        np.asarray(ref.modmatmul_ref(jnp.asarray(db), server.a_matrix)))
+    padded = ops.kernel_db(server.db, "pallas")
+    assert padded.shape == (m, 512) and padded.sharding == server.db.sharding
+    text = server._answer_fn.lower(
+        padded, jax.device_put(q, server._replicated)).as_text()
+    assert "x300xui8" not in text
+
+    cols = jnp.asarray([5, 299])
+    db[:, [5, 299]] = rng.integers(0, 256, (m, 2), dtype=np.uint8)
+    server.update_columns(cols, jnp.asarray(db[:, [5, 299]]), donate=True)
+    a3 = server.answer(q)
+    assert cache.misses == 2
+    want = lwe.switch_modulus(ref.modmatmul_ref(jnp.asarray(db), q),
+                              cfg.params.q_switch)
+    np.testing.assert_array_equal(np.asarray(a3), np.asarray(want))

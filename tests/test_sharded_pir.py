@@ -301,3 +301,68 @@ np.testing.assert_array_equal(np.asarray(s1.answer(q)),
                               np.asarray(s8.answer(q)))
 print("OK sharded update bitwise")
 """)
+
+
+def test_row_sharded_answer_programs_and_pad_once_per_array():
+    """The 8-device server's answer is its one row-shard program at every
+    batch width, switched or not: bit-identical to the oracle GEMM and the
+    modulus switch, named jit_local, no collective.  A DB width the kernel
+    cannot take in place (300 → 512) is padded once per DB array, outside
+    the programs: two answers and the hint share one padded copy, and a
+    commit's new array misses once."""
+    run_sub("""
+from repro.core import lwe, pir
+from repro.kernels import ops, ref
+
+mesh = make_mesh((8,), ("chunks",))
+rng = np.random.default_rng(0)
+COLL = ("all-reduce", "all-gather", "all-to-all", "collective-permute",
+        "reduce-scatter")
+m = 512
+for n, impl in ((512, "xla"), (512, "pallas")):
+    db = rng.integers(0, 256, (m, n), dtype=np.uint8)
+    for q_switch in (None, 1 << 16):
+        srv = pir.PIRServer(pir.make_config(m, n, impl=impl,
+                                            q_switch=q_switch),
+                            db, mesh=mesh)
+        for b in (1, 7, 16, 40):
+            q = jnp.asarray(rng.integers(0, 2**32, (n, b), dtype=np.uint32))
+            want = ref.modmatmul_ref(jnp.asarray(db), q)
+            if q_switch is not None:
+                want = lwe.switch_modulus(want, q_switch)
+            np.testing.assert_array_equal(np.asarray(srv.answer(q)),
+                                          np.asarray(want))
+            text = srv._answer_fn.lower(
+                ops.kernel_db(srv.db, impl),
+                jax.device_put(q, srv._replicated)).compile().as_text()
+            assert text.startswith("HloModule jit_local"), text[:80]
+            assert not [c for c in COLL if c in text], (impl, q_switch, b)
+
+n = 300
+db = rng.integers(0, 256, (m, n), dtype=np.uint8)
+cfg = pir.make_config(m, n, impl="pallas")
+srv = pir.PIRServer(cfg, db, mesh=mesh)
+cache = ops._db_pad_cache
+cache.clear()
+q = jnp.asarray(rng.integers(0, 2**32, (n, 3), dtype=np.uint32))
+a1, a2, hint = srv.answer(q), srv.answer(q), srv.setup()
+assert (cache.misses, cache.hits) == (1, 2), (cache.misses, cache.hits)
+padded = ops.kernel_db(srv.db, "pallas")
+assert padded.shape == (m, 512) and padded.sharding == srv.db.sharding
+want = lwe.switch_modulus(ref.modmatmul_ref(jnp.asarray(db), q),
+                          cfg.params.q_switch)
+np.testing.assert_array_equal(np.asarray(a1), np.asarray(want))
+np.testing.assert_array_equal(np.asarray(a2), np.asarray(want))
+np.testing.assert_array_equal(
+    np.asarray(hint), np.asarray(ref.modmatmul_ref(jnp.asarray(db),
+                                                   srv.a_matrix)))
+cols = jnp.asarray([5, 299])
+db[:, [5, 299]] = rng.integers(0, 256, (m, 2), dtype=np.uint8)
+srv.update_columns(cols, jnp.asarray(db[:, [5, 299]]), donate=True)
+a3 = srv.answer(q)
+assert cache.misses == 2, cache.misses
+want = lwe.switch_modulus(ref.modmatmul_ref(jnp.asarray(db), q),
+                          cfg.params.q_switch)
+np.testing.assert_array_equal(np.asarray(a3), np.asarray(want))
+print("OK one row-shard program, DB padded once per array")
+""")

@@ -113,12 +113,14 @@ def test_client_batched_encrypt_compiles(one_chip, c):
     assert "ENTRY" in text
 
 
-def _row_shard_answer(topo, rows: int, b: int) -> str:
-    """The compiled row-sharded answer over four chips of ``rows`` each."""
-    mesh = make_chunk_mesh(4, topo.devices)
-    fn = collectives.row_shard_gemm(mesh, ("chunks",), q_switch=1 << 16)
+def _row_shard_answer(topo, rows: int, b: int, chips: int = 4,
+                      q_switch: int | None = 1 << 16) -> str:
+    """The compiled row-sharded answer over ``chips`` chips of ``rows``
+    each (with ``q_switch=None``, the hint GEMM's program)."""
+    mesh = make_chunk_mesh(chips, topo.devices[:chips])
+    fn = collectives.row_shard_gemm(mesh, ("chunks",), q_switch=q_switch)
     return fn.lower(
-        _sds((4 * rows, 4096), jnp.uint8,
+        _sds((chips * rows, 4096), jnp.uint8,
              NamedSharding(mesh, P("chunks", None))),
         _sds((4096, b), jnp.uint32, NamedSharding(mesh, P()))
     ).compile().as_text()
@@ -137,5 +139,18 @@ def test_row_shard_gemm_at_the_sharded_cells_rows(topo, on_tpu, b):
     """dpr-wiki.sharded4's answer: 266,304 DB rows a chip, a serving batch
     of b queries copied to every chip; the kernel, and no collective."""
     text = _row_shard_answer(topo, 266_304, b)
+    assert "tpu_custom_call" in text
+    assert not [c for c in COLLECTIVES if c in text]
+
+
+@pytest.mark.parametrize("b, q_switch", [(1, 1 << 16), (16, 1 << 16),
+                                         (1024, None)])
+def test_row_shard_gemm_on_one_chip_at_msmarco_rows(topo, on_tpu, b,
+                                                    q_switch):
+    """msmarco.steady's server on one chip, a one-device mesh: the answer
+    at b = 1 and 16 and the hint's 1024 columns over all 1,559,040 DB rows,
+    each one program named jit_local with the kernel in it."""
+    text = _row_shard_answer(topo, 1_559_040, b, chips=1, q_switch=q_switch)
+    assert text.startswith("HloModule jit_local")
     assert "tpu_custom_call" in text
     assert not [c for c in COLLECTIVES if c in text]
